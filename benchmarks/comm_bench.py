@@ -179,12 +179,12 @@ def fused_kernel_bench(n: int = 25, d: int = 16384, b: int = 2, reps: int = 1):
             fn().block_until_ready()
         return (time.perf_counter() - t0) / reps * 1e6
 
-    us_fused = timeit(lambda: ops.dequant_trimmed_mean(q, scale, mask, sv, b, block_d=512))
+    us_fused = timeit(lambda: ops.dequant_trimmed_mean(q, scale, mask, sv, b, block_d=512, interpret=True))
     us_staged = timeit(lambda: ops.trimmed_mean(
-        ops.dequant(q, scale, block_d=512), mask, sv, b, block_d=512))
+        ops.dequant(q, scale, block_d=512, interpret=True), mask, sv, b, block_d=512, interpret=True))
     us_ref = timeit(jax.jit(
         lambda: ref.dequant_trimmed_mean_ref(q, scale, mask, sv, b)).lower().compile())
-    out_f = np.asarray(ops.dequant_trimmed_mean(q, scale, mask, sv, b, block_d=512))
+    out_f = np.asarray(ops.dequant_trimmed_mean(q, scale, mask, sv, b, block_d=512, interpret=True))
     out_r = np.asarray(ref.dequant_trimmed_mean_ref(q, scale, mask, sv, b))
     agree = bool(np.allclose(out_f, out_r, rtol=1e-5, atol=1e-5))
     return {

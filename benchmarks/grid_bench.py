@@ -90,12 +90,23 @@ def grid_throughput(
     """Returns CSV rows and writes BENCH_grid.json."""
     from repro.sim.grid import default_topology
 
+    topo = default_topology(num_nodes, rules, (num_byzantine,), seed=seed)
+    grid = ExperimentGrid(topo, rules, attacks, (num_byzantine,), seeds, lam=1.0, t0=30.0)
+    n_base = min(baseline_cells, grid.num_cells)
+    # subprocess baseline first, while this process has not yet touched a
+    # JAX backend: a chip belongs to one process, so children started after
+    # the parent holds it would fail or hang
+    if subprocess_baseline:
+        sub_s = _subprocess_cell_seconds(grid.cells()[:n_base], num_nodes, ticks)
+        sub_cps = 1.0 / sub_s
+    else:
+        sub_s = sub_cps = None
+
     x, y, xt, yt = get_data()
     shards = partition_iid(x, y, num_nodes, seed=seed)
     # stack_node_batches closures are stateful (the rng advances per call):
     # every consumer gets a FRESH closure so all paths see the same draws
     fresh_batch_fn = lambda: stack_node_batches(shards, 32, seed=seed)
-    topo = default_topology(num_nodes, rules, (num_byzantine,), seed=seed)
     grad_fn = make_grad_fn("linear")
     bf = fresh_batch_fn()
     batches = stack_batches(
@@ -105,7 +116,6 @@ def grid_throughput(
         key = jax.random.PRNGKey(s)
         return replicate(small.init_linear(key), num_nodes, perturb=0.01, key=key)
 
-    grid = ExperimentGrid(topo, rules, attacks, (num_byzantine,), seeds, lam=1.0, t0=30.0)
     engine = GridEngine(grid, grad_fn)
     e = engine.num_cells
 
@@ -124,7 +134,6 @@ def grid_throughput(
     compile_s = max(wall_grid - wall_steady, 0.0)
 
     # in-process sequential baseline: fresh trainer (trace + compile) per cell
-    n_base = min(baseline_cells, e)
     t0 = time.perf_counter()
     base_final = {}
     for c in engine.cells[:n_base]:
@@ -140,13 +149,8 @@ def grid_throughput(
         base_final[c.tag] = st.params
     wall_seq = time.perf_counter() - t0
     seq_cps = n_base / wall_seq
-
-    # subprocess baseline: what the fan-out sweep actually pays per cell
-    if subprocess_baseline:
-        sub_s = _subprocess_cell_seconds(engine.cells[:n_base], num_nodes, ticks)
-        sub_cps = 1.0 / sub_s
-    else:  # pragma: no cover - smoke-speed escape hatch
-        sub_s, sub_cps = None, seq_cps
+    if sub_cps is None:  # smoke-speed escape hatch
+        sub_cps = seq_cps
 
     # correctness anchor: the measured speedup compares identical experiments.
     # The protocol pipeline (attack/screen/update) is bit-identical by

@@ -58,7 +58,7 @@ def kernel_throughput(n=25, b=2, dims=(4096, 65536, 1048576)):
         steady_total += (us_ref + us_med) / 1e6
         if d <= 65536:  # interpret mode is python-speed; keep it bounded
             us_pl, _ = _time(
-                lambda v=vals, m=mask, s=sv: ops.trimmed_mean(v, m, s, b, block_d=512),
+                lambda v=vals, m=mask, s=sv: ops.trimmed_mean(v, m, s, b, block_d=512, interpret=True),
                 reps=1,
             )
             rows.append((f"kernel/trimmed_mean_pallas_interp/d{d}", us_pl,

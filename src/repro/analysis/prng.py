@@ -16,7 +16,7 @@ the jaxpr obscures key identity three ways:
 * ``random_split`` outputs are unwrapped and then sliced per subkey — slices
   with different ``start_indices`` hash to different numbers and correctly
   stay distinct keys;
-* the high-level samplers appear as ``pjit[name=_normal/...]`` sub-jaxprs —
+* the high-level samplers appear as ``jit[name=_normal/...]`` sub-jaxprs —
   the walk recurses with the caller's value numbers bound to the callee's
   invars, so key identity crosses the call boundary.
 
@@ -28,7 +28,7 @@ Counting discipline (what is and is not a violation):
   shared-randomness idiom (every node reading the same public coin, a
   loop-invariant draw equal to its hoisted form) and counts once.  The
   consumer's number includes the outermost sampler frame (the first
-  ``pjit[name=_normal/_uniform/...]`` wrapper on the path — ``normal``
+  ``jit[name=_normal/_uniform/...]`` wrapper on the path — ``normal``
   *internally* calls ``_uniform``, so the innermost frame cannot tell the
   two apart), so two *distributions* drawing the same raw bits from one
   key — bitwise equal bits but statistically correlated samples — stay
@@ -116,7 +116,7 @@ class _Walker:
     def __init__(self):
         self._n = 0
         self.uses: UseTable = {}
-        self._frame: str | None = None  # outermost sampler (_-named pjit) frame
+        self._frame: str | None = None  # outermost sampler (_-named jit) frame
 
     def fresh(self, label: str):
         self._n += 1
@@ -166,7 +166,7 @@ class _Walker:
             self.uses[key] = self.uses.get(key, frozenset()) | {consumer}
             # fall through to generic value numbering of the output
 
-        elif prim == "pjit" or "call_jaxpr" in eqn.params or "fun_jaxpr" in eqn.params:
+        elif prim == "jit" or "call_jaxpr" in eqn.params or "fun_jaxpr" in eqn.params:
             closed = (eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
                       or eqn.params.get("fun_jaxpr"))
             inner = closed.jaxpr if hasattr(closed, "jaxpr") else closed

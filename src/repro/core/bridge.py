@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import dataclasses
 import time
-import warnings
 from collections.abc import Callable
 from typing import Any, NamedTuple
 
@@ -1008,13 +1007,7 @@ class BridgeTrainer:
             hi = min(done + chunk, start + num_steps)
             xs = stack_batches(lambda i: batch_fn(done + i), hi - done)
             t_chunk = time.perf_counter()
-            with warnings.catch_warnings():
-                # backends without buffer donation (older CPU jaxlibs) warn
-                # per compile; the donation is an optimization, not a
-                # correctness requirement
-                warnings.filterwarnings(
-                    "ignore", message=".*[Dd]onat.*", category=UserWarning)
-                state, ms = scan_chunk(self._cell, state, xs)
+            state, ms = scan_chunk(self._cell, state, xs)
             # host work below overlaps the dispatched device computation:
             # the writer copies the ring and device_gets on its own thread
             if writer is not None:

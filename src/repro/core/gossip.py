@@ -37,11 +37,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import screening
 
-try:  # jax >= 0.5 exports shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 _COORD_RULES = ("trimmed_mean", "median", "mean")
 
 
@@ -143,7 +138,7 @@ def coordwise_gossip_leaf(
         return out.reshape(x.shape[1:])[None]
 
     body = ag_body if schedule == "all_gather" else a2a_body
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, P(), P(), P(), P()),

@@ -68,8 +68,9 @@ def sum_rows_mat(x: jax.Array) -> jax.Array:
     rows, clipped-mean's scaled deltas): a ``lax.scan`` *materializes* its
     ``xs`` operand, so the producer multiply is rounded to storage precision
     before the loop and the body is a pure, contraction-proof add.
-    (``optimization_barrier`` would be cheaper but has no batching rule on
-    jax 0.4.x.)"""
+    (Whether ``optimization_barrier`` would be a cheaper fence here is not
+    measured; the scan is part of the bit-identity contract until a chip run
+    says otherwise.)"""
     n = x.shape[0]
     if n > 64:
         return jnp.sum(x, axis=0)

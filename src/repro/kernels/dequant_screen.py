@@ -23,6 +23,9 @@ wire layout) / ``mask [n]`` / ``self_value [d]`` -> ``[d]``, with an optional
 leading experiment axis (``[E, n, d]`` etc.) mapped onto the first Pallas
 grid dimension.  ``b`` is static; ``block_d`` must be a multiple of
 `SCALE_BLOCK` so each grid step's scale slice aligns with its coordinates.
+Inside the kernels the scales travel as one ``[2, n, block_d / SCALE_BLOCK]``
+tile per coordinate block (`scale_blocks`), a layout whose block shape the
+TPU compiler accepts.
 """
 from __future__ import annotations
 
@@ -33,53 +36,62 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.comm.codec import SCALE_BLOCK
-from repro.kernels.median import _median_block
+from repro.kernels.median import _median_block, _with_self
 from repro.kernels.trimmed_mean import _trimmed_mean_block
 
 _INF = float("inf")
 
 
-def _dequant_rows(q, scale):
-    """[n, blk] int8 codes + [n, sb, 2] per-block affine pairs -> guarded
-    f32 rows (sb = blk / SCALE_BLOCK)."""
-    n, blk = q.shape
-    sb = scale.shape[1]
-    qb = q.astype(jnp.float32).reshape(n, sb, blk // sb)
-    v = (qb * scale[:, :, 0:1] + scale[:, :, 1:2]).reshape(n, blk)
+def _dequant_rows(q, sz):
+    """``[n, blk]`` int8 codes + ``sz [2, n, sb]`` per-`SCALE_BLOCK` scale and
+    zero columns -> guarded f32 rows (sb = blk / SCALE_BLOCK).  Each
+    128-lane slice takes its own column, broadcast along lanes."""
+    qf = q.astype(jnp.float32)
+    parts = [qf[:, t * SCALE_BLOCK:(t + 1) * SCALE_BLOCK] * sz[0][:, t:t + 1] + sz[1][:, t:t + 1]
+             for t in range(sz.shape[-1])]
+    v = jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
     # abused scales decode to inf; inf * 0 codes to NaN — guard to +inf so
     # rank-based screening trims them as maximal outliers (core.screening)
     return jnp.where(jnp.isnan(v), _INF, v)
 
 
 def _dequant_kernel(q_ref, scale_ref, out_ref):
-    out_ref[0] = _dequant_rows(q_ref[0], scale_ref[0]).astype(out_ref.dtype)
+    out_ref[0] = _dequant_rows(q_ref[0], scale_ref[0, 0]).astype(out_ref.dtype)
 
 
 def _fused_tm_kernel(q_ref, scale_ref, mask_ref, self_ref, out_ref, *, b: int):
-    v = _dequant_rows(q_ref[0], scale_ref[0])  # [n, blk]
-    valid = (mask_ref[0] > 0.5) & jnp.ones_like(v, dtype=bool)
-    self_value = self_ref[0][0].astype(jnp.float32)  # [blk]
-    out_ref[0] = _trimmed_mean_block(v, valid, self_value, b).astype(out_ref.dtype)[None]
+    v = _dequant_rows(q_ref[0], scale_ref[0, 0])  # [n, blk]
+    self_value = self_ref[0].astype(jnp.float32)  # [1, blk]
+    out_ref[0] = _trimmed_mean_block(v, mask_ref[0], self_value, b).astype(out_ref.dtype)
 
 
 def _fused_med_kernel(q_ref, scale_ref, mask_ref, self_ref, out_ref):
-    v = _dequant_rows(q_ref[0], scale_ref[0])  # [n, blk]
-    self_row = self_ref[0].astype(jnp.float32)  # [1, blk]
-    # Eq. (11) medians over N_j ∪ {j}: the node's own (never-compressed)
-    # iterate joins the dequantized neighbor rows inside the block
-    rows = jnp.concatenate([v, jnp.where(jnp.isnan(self_row), _INF, self_row)], axis=0)
-    valid = jnp.concatenate(
-        [(mask_ref[0] > 0.5) & jnp.ones_like(v, dtype=bool),
-         jnp.ones_like(self_row, dtype=bool)], axis=0)
-    out_ref[0] = _median_block(rows, valid).astype(out_ref.dtype)[None]
+    v = _dequant_rows(q_ref[0], scale_ref[0, 0])  # [n, blk]
+    # the node's own (never-compressed) iterate joins the dequantized
+    # neighbor rows inside the block
+    rows, valid = _with_self(v, mask_ref[0], self_ref[0].astype(jnp.float32))
+    out_ref[0] = _median_block(rows, valid).astype(out_ref.dtype)
 
 
-def _prep(q, scale, mask, self_value, block_d, interpret):
-    """Shared batching/padding: returns (e, n, d, padded operands, grid)."""
+def scale_blocks(scale: jax.Array, s_need: int, block_d: int) -> jax.Array:
+    """Wire-layout scales ``[..., R, S, 2]`` -> kernel layout
+    ``[..., S_pad / sb, 2, R, sb]`` (sb = block_d / SCALE_BLOCK): one
+    ``[2, R, sb]`` tile per coordinate block, whose trailing two dims equal
+    the array's — the TPU block-shape rule.  Scale blocks are zero-padded to
+    ``s_need`` so the zero-padded coordinate tail decodes to exact zeros."""
+    sb = block_d // SCALE_BLOCK
+    lead, (r, s) = scale.shape[:-3], scale.shape[-3:-1]
+    pad = [(0, 0)] * len(lead) + [(0, 0), (0, s_need - s), (0, 0)]
+    sc = jnp.pad(scale, pad).reshape(lead + (r, s_need // sb, sb, 2))
+    k = len(lead)
+    return jnp.transpose(sc, tuple(range(k)) + (k + 1, k + 3, k, k + 2))
+
+
+def _prep(q, scale, mask, self_value, block_d):
+    """Shared batching/padding: returns (squeeze, e, n, d, padded d, and the
+    padded codes, scale tiles, mask and self operands)."""
     if block_d % SCALE_BLOCK:
         raise ValueError(f"block_d must be a multiple of {SCALE_BLOCK}, got {block_d}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     squeeze = q.ndim == 2
     if squeeze:
         q, scale, mask = q[None], scale[None], mask[None]
@@ -88,15 +100,16 @@ def _prep(q, scale, mask, self_value, block_d, interpret):
     e, n, d = q.shape
     pad_d = (-d) % block_d
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, pad_d)))
-    # scale blocks padded to cover the padded coordinate range (zero scale
-    # decodes the zero-padded tail to exact zeros)
-    s_need = (d + pad_d) // SCALE_BLOCK
-    scp = jnp.pad(scale, ((0, 0), (0, 0), (0, s_need - scale.shape[2]), (0, 0)))
+    scp = scale_blocks(scale, (d + pad_d) // SCALE_BLOCK, block_d)  # [E, nb, 2, n, sb]
     sp = None
     if self_value is not None:
         sp = jnp.pad(self_value, ((0, 0), (0, pad_d)))[:, None, :]  # [E, 1, dpad]
     mp = None if mask is None else mask.astype(jnp.float32)[:, :, None]  # [E, n, 1]
-    return squeeze, interpret, e, n, d, d + pad_d, qp, scp, mp, sp
+    return squeeze, e, n, d, d + pad_d, qp, scp, mp, sp
+
+
+def _scale_spec(n: int, block_d: int):
+    return pl.BlockSpec((1, 1, 2, n, block_d // SCALE_BLOCK), lambda ei, i: (ei, i, 0, 0, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
@@ -105,21 +118,21 @@ def dequant_pallas(
     scale: jax.Array,
     *,
     block_d: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Standalone decode: ``q [n, d]`` (or ``[E, n, d]``) int8 codes +
     ``scale [n, 2]`` affine pairs -> guarded ``float32`` values.  This is the
     first stage of the *unfused* decode-then-screen pipeline the fused
     kernels are benchmarked against (it materializes the float32 tensor the
     fused path never writes)."""
-    squeeze, interpret, e, n, d, dp, qp, sc, _, _ = _prep(
-        q, scale, jnp.ones(q.shape[:-1], bool), None, block_d, interpret)
+    squeeze, e, n, d, dp, qp, sc, _, _ = _prep(
+        q, scale, jnp.ones(q.shape[:-1], bool), None, block_d)
     out = pl.pallas_call(
         _dequant_kernel,
         grid=(e, dp // block_d),
         in_specs=[
             pl.BlockSpec((1, n, block_d), lambda ei, i: (ei, 0, i)),
-            pl.BlockSpec((1, n, block_d // SCALE_BLOCK, 2), lambda ei, i: (ei, 0, i, 0)),
+            _scale_spec(n, block_d),
         ],
         out_specs=pl.BlockSpec((1, n, block_d), lambda ei, i: (ei, 0, i)),
         out_shape=jax.ShapeDtypeStruct((e, n, dp), jnp.float32),
@@ -138,18 +151,18 @@ def dequant_trimmed_mean_pallas(
     b: int,
     *,
     block_d: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Fused int8-codeword trimmed-mean screening (BRIDGE-T): dequantize each
     VMEM block and screen it in one pass — ``float32 [n, d]`` never exists."""
-    squeeze, interpret, e, n, d, dp, qp, sc, mp, sp = _prep(
-        q, scale, mask, self_value, block_d, interpret)
+    squeeze, e, n, d, dp, qp, sc, mp, sp = _prep(
+        q, scale, mask, self_value, block_d)
     out = pl.pallas_call(
         functools.partial(_fused_tm_kernel, b=b),
         grid=(e, dp // block_d),
         in_specs=[
             pl.BlockSpec((1, n, block_d), lambda ei, i: (ei, 0, i)),
-            pl.BlockSpec((1, n, block_d // SCALE_BLOCK, 2), lambda ei, i: (ei, 0, i, 0)),
+            _scale_spec(n, block_d),
             pl.BlockSpec((1, n, 1), lambda ei, i: (ei, 0, 0)),
             pl.BlockSpec((1, 1, block_d), lambda ei, i: (ei, 0, i)),
         ],
@@ -169,18 +182,18 @@ def dequant_median_pallas(
     self_value: jax.Array,
     *,
     block_d: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Fused int8-codeword coordinate-median screening (BRIDGE-M) over
     N_j ∪ {j}; the self row joins uncompressed inside the kernel."""
-    squeeze, interpret, e, n, d, dp, qp, sc, mp, sp = _prep(
-        q, scale, mask, self_value, block_d, interpret)
+    squeeze, e, n, d, dp, qp, sc, mp, sp = _prep(
+        q, scale, mask, self_value, block_d)
     out = pl.pallas_call(
         _fused_med_kernel,
         grid=(e, dp // block_d),
         in_specs=[
             pl.BlockSpec((1, n, block_d), lambda ei, i: (ei, 0, i)),
-            pl.BlockSpec((1, n, block_d // SCALE_BLOCK, 2), lambda ei, i: (ei, 0, i, 0)),
+            _scale_spec(n, block_d),
             pl.BlockSpec((1, n, 1), lambda ei, i: (ei, 0, 0)),
             pl.BlockSpec((1, 1, block_d), lambda ei, i: (ei, 0, i)),
         ],

@@ -32,11 +32,9 @@ def pairwise_sq_dists_pallas(
     stacked: jax.Array,
     *,
     block_d: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """[n, n] squared euclidean distances between rows of ``stacked [n, d]``."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n, d = stacked.shape
     pad_d = (-d) % block_d
     xp = jnp.pad(stacked, ((0, 0), (0, pad_d)))

@@ -30,28 +30,36 @@ _INF = float("inf")
 
 
 def _median_block(values, valid):
-    """Median over axis 0 of one [n, blk] block under the [n, blk] mask."""
+    """Median over axis 0 of one ``[n, blk]`` block under the ``[n, 1]`` float
+    0/1 per-row mask.  Returns ``[1, blk]``.
+
+    Row i's rank is accumulated for all rows at once, one neighbor j per
+    unrolled step (j precedes i when ``v_j < v_i`` or, tied, ``j < i``), so
+    every operand stays a full ``[n, blk]`` tile."""
     n = values.shape[0]
-    count = jnp.sum(valid[:, :1].astype(jnp.int32))  # cardinality (per-row mask)
+    count = jnp.sum(valid).astype(jnp.int32)  # cardinality (per-row mask)
     lo = (count - 1) // 2
     hi = count // 2
-    v = jnp.where(valid, values, _INF)
-    acc_lo = jnp.zeros_like(values[0])
-    acc_hi = jnp.zeros_like(values[0])
-    for i in range(n):
-        vi = v[i]
-        # rank of row i among valid entries (lexicographic tie-break by row)
-        less = jnp.zeros_like(vi, dtype=jnp.int32)
-        for j in range(n):
-            if j == i:
-                continue
-            vj = v[j]
-            prec = (vj < vi) | ((vj == vi) & (j < i))
-            less = less + (prec & valid[j]).astype(jnp.int32)
-        ok = valid[i]
-        acc_lo = acc_lo + jnp.where(ok & (less == lo), vi, 0.0)
-        acc_hi = acc_hi + jnp.where(ok & (less == hi), vi, 0.0)
+    rows = jax.lax.broadcasted_iota(jnp.int32, values.shape, 0)
+    validb = jnp.broadcast_to(valid, values.shape)
+    ok = validb > 0.5
+    v = jnp.where(ok, values, _INF)
+    less = jnp.zeros(values.shape, jnp.int32)
+    for j in range(n):
+        vj = v[j:j + 1]
+        prec = (vj < v) | ((vj == v) & (rows > j))
+        less = less + jnp.where(prec & (validb[j:j + 1] > 0.5), 1, 0)
+    acc_lo = jnp.sum(jnp.where(ok & (less == lo), v, 0.0), axis=0, keepdims=True)
+    acc_hi = jnp.sum(jnp.where(ok & (less == hi), v, 0.0), axis=0, keepdims=True)
     return 0.5 * (acc_lo + acc_hi)
+
+
+def _with_self(v, valid, self_row):
+    """Eq. (11) medians over N_j ∪ {j}: append the node's own ``[1, blk]``
+    row (NaN-guarded, always valid) to a ``[K, blk]`` neighborhood and its
+    ``[K, 1]`` mask."""
+    rows = jnp.concatenate([v, jnp.where(jnp.isnan(self_row), _INF, self_row)], axis=0)
+    return rows, jnp.concatenate([valid, jnp.ones((1, 1), jnp.float32)], axis=0)
 
 
 def _kernel(values_ref, mask_ref, out_ref):
@@ -59,9 +67,7 @@ def _kernel(values_ref, mask_ref, out_ref):
     # NaN payloads -> +inf so rank-counting stays total-ordered (matches
     # repro.core.screening's guard)
     values = jnp.where(jnp.isnan(values), _INF, values)
-    mask = mask_ref[0]
-    valid = (mask > 0.5) & jnp.ones_like(values, dtype=bool)
-    out_ref[0] = _median_block(values, valid).astype(out_ref.dtype)[None]
+    out_ref[0] = _median_block(values, mask_ref[0]).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
@@ -70,12 +76,10 @@ def median_pallas(
     mask: jax.Array,
     *,
     block_d: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Masked coordinate-wise median of ``values [n, d]`` (or ``[E, n, d]``)
     over the neighbor axis."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     squeeze = values.ndim == 2
     if squeeze:
         values, mask = values[None], mask[None]

@@ -1,8 +1,10 @@
 """Public jit'd wrappers for the screening kernels.
 
-``use_pallas`` selects the Pallas TPU path (interpret-mode on CPU) vs the
-pure-jnp reference; both produce identical results — the dispatcher lets the
-trainer flip implementations per platform/config.
+``use_pallas`` selects the Pallas TPU path vs the pure-jnp reference; both
+produce identical results.  The Pallas path compiles for the TPU unless the
+caller passes ``interpret=True`` (the CPU tests and benchmarks do): nothing
+here picks interpret mode from the backend, so a run without a chip fails
+instead of silently timing the Pallas interpreter.
 
 Every entry point runs under a ``jax.named_scope`` (``kernels.<name>``) so
 ``jax.profiler`` captures (``--profile`` on the launch CLIs) attribute

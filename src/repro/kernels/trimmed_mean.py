@@ -30,42 +30,31 @@ from jax.experimental import pallas as pl
 _INF = float("inf")
 
 
-def _first_true(flags: jax.Array) -> jax.Array:
-    """Per-coordinate mask of the first True row (axis 0), without cumsum.
-
-    Pallas-TPU friendly: uses a running 'seen' accumulator over the static
-    neighbor axis (unrolled python loop) instead of lax.cumsum.
-    """
-    n = flags.shape[0]
-    rows = []
-    seen = jnp.zeros_like(flags[0])
-    for i in range(n):
-        take = flags[i] & ~seen
-        rows.append(take)
-        seen = seen | flags[i]
-    return jnp.stack(rows, axis=0)
-
-
 def _trimmed_mean_block(values, valid, self_value, b: int):
-    """Screen one [n, blk] block; `valid` is the [n, blk] neighbor mask.
+    """Screen one ``[n, blk]`` block against ``self_value [1, blk]``; ``valid``
+    is the ``[n, 1]`` float 0/1 per-row neighbor mask.  Returns ``[1, blk]``.
 
-    The trim width is clamped to ``min(b, (count - 1) // 2)`` exactly like
+    Each extraction removes the *first* row holding the running extremum
+    (ties broken by row index) via a min over a row iota — no bool stacking
+    or cumsum, which Mosaic cannot lower.  The trim width is clamped to
+    ``min(b, (count - 1) // 2)`` exactly like
     `repro.core.screening.effective_trim`: identical at or above Table II's
     ``2b + 1`` minimum, and degrades instead of dividing through zero on a
     starved neighborhood (dynamic schedules)."""
-    count = jnp.sum(valid[:, :1].astype(jnp.float32))  # |N_j| (mask is per-row)
+    n = values.shape[0]
+    count = jnp.sum(valid)  # |N_j| (mask is per-row)
     b_eff = jnp.minimum(jnp.float32(b), jnp.floor(jnp.maximum(count - 1.0, 0.0) / 2.0))
-    m = valid
+    rows = jax.lax.broadcasted_iota(jnp.int32, values.shape, 0)
     v = values
-    for i in range(b):  # drop up to b maxima (gated by the clamp)
-        cur = jnp.max(jnp.where(m, v, -_INF), axis=0, keepdims=True)
-        hit = _first_true((v == cur) & m)
-        m = m & ~(hit & (i < b_eff))
-    for i in range(b):  # drop up to b minima
-        cur = jnp.min(jnp.where(m, v, _INF), axis=0, keepdims=True)
-        hit = _first_true((v == cur) & m)
-        m = m & ~(hit & (i < b_eff))
-    total = jnp.sum(jnp.where(m, v, 0.0), axis=0) + self_value
+    m = jnp.broadcast_to(valid, values.shape) > 0.5
+    for i in range(2 * b):  # drop up to b maxima, then up to b minima
+        top = i < b
+        fill = -_INF if top else _INF
+        red = jnp.max if top else jnp.min
+        cur = red(jnp.where(m, v, fill), axis=0, keepdims=True)
+        first = jnp.min(jnp.where((v == cur) & m, rows, n), axis=0, keepdims=True)
+        m = m & ~((rows == first) & (i % b < b_eff))
+    total = jnp.sum(jnp.where(m, v, 0.0), axis=0, keepdims=True) + self_value
     return total / (count - 2 * b_eff + 1)
 
 
@@ -74,12 +63,9 @@ def _kernel(values_ref, mask_ref, self_ref, out_ref, *, b: int):
     # NaN payloads -> +inf so they are trimmed as maximal outliers instead of
     # poisoning the max/min extraction (matches repro.core.screening)
     values = jnp.where(jnp.isnan(values), _INF, values)
-    mask = mask_ref[0]  # [n, 1] float (0/1)
-    self_value = self_ref[0]  # [1, blk]
-    valid = (mask > 0.5) & jnp.ones_like(values, dtype=bool)
     out_ref[0] = _trimmed_mean_block(
-        values, valid, self_value[0].astype(jnp.float32), b
-    ).astype(out_ref.dtype)[None]
+        values, mask_ref[0], self_ref[0].astype(jnp.float32), b
+    ).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("b", "block_d", "interpret"))
@@ -90,12 +76,10 @@ def trimmed_mean_pallas(
     b: int,
     *,
     block_d: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Trimmed-mean screening of ``values [n, d]`` (or ``[E, n, d]``) against
     ``self_value [d]`` (or ``[E, d]``)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     squeeze = values.ndim == 2
     if squeeze:
         values, mask, self_value = values[None], mask[None], self_value[None]

@@ -11,10 +11,12 @@ Usage:
 One (arch, shape, mesh) per invocation — the sweep script
 (launch/sweep.py) fans out subprocesses and aggregates the table.
 """
-# The VERY FIRST jax-visible action: force 512 placeholder devices BEFORE any
-# other import (jax locks the device count on first backend init).
+# The VERY FIRST jax-visible action: add 512 placeholder devices to whatever
+# XLA_FLAGS the environment set, BEFORE any other import (jax locks the
+# device count on first backend init).
 import os  # noqa: E402
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = " ".join(
+    filter(None, [os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512"]))
 
 import argparse
 import json

@@ -4,20 +4,13 @@ state."""
 from __future__ import annotations
 
 import jax
-
-
-def make_mesh_compat(shape, axes, **kwargs):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` only exists from
-    jax 0.5; older versions treat every axis as Auto already."""
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs.setdefault("axis_types", (jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes, **kwargs)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def node_axes(mesh) -> tuple:
@@ -34,4 +27,4 @@ def num_nodes(mesh) -> int:
 def make_host_mesh(data: int = 2, model: int = 2):
     """Tiny mesh over host CPU devices for tests (requires
     XLA_FLAGS=--xla_force_host_platform_device_count >= data*model)."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2)

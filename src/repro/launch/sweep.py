@@ -276,6 +276,13 @@ def run_grid_mode(args) -> None:
         print(f"  {row[0]:60s} acc={rec['accuracy']:.4f} loss={rec['final_loss']:.4f}")
 
 
+def _fanout_workers(jobs: int) -> int:
+    """Concurrent children for the subprocess modes: ``--jobs`` only when JAX
+    is held to the CPU.  An accelerator belongs to one process at a time, so
+    anywhere else the children run one after another."""
+    return jobs if os.environ.get("JAX_PLATFORMS") == "cpu" else 1
+
+
 def _trust_spec(args):
     """The `repro.trust.TrustSpec` the --trust flags describe (None when
     --trust is off — the trust-free program, bit-identical to PR 6)."""
@@ -345,7 +352,9 @@ def main(argv=None):
     ap.add_argument("--mode", default="dryrun",
                     choices=["dryrun", "net", "grid", "breakdown"])
     ap.add_argument("--out", default=None)
-    ap.add_argument("--jobs", type=int, default=4)
+    ap.add_argument("--jobs", type=int, default=4,
+                    help="concurrent subprocess jobs (dryrun/net modes); "
+                         "honoured only under JAX_PLATFORMS=cpu, else 1")
     ap.add_argument("--timeout", type=int, default=1500)
     ap.add_argument("--archs", default=None)
     ap.add_argument("--shapes", default=None)
@@ -423,6 +432,9 @@ def main(argv=None):
     ap.add_argument("--trust-warmup", type=int, default=8,
                     help="ticks before evictions can latch")
     args = ap.parse_args(argv)
+    from repro.launch.cache import use_compilation_cache
+
+    use_compilation_cache()
     if args.out is None:
         args.out = {"net": "experiments/net", "grid": "experiments/grid",
                     "breakdown": "experiments/breakdown"}.get(
@@ -444,13 +456,14 @@ def main(argv=None):
         args.scenarios = ",".join(NET_SCENARIOS)
     if args.attacks is None:
         args.attacks = "random,alie,selective_victim"
+    workers = _fanout_workers(args.jobs)
     if args.mode == "net":
         jobs = [(r, a, s)
                 for r in args.rules.split(",")
                 for a in args.attacks.split(",")
                 for s in args.scenarios.split(",")]
-        print(f"{len(jobs)} net-scenario jobs -> {args.out}")
-        with ThreadPoolExecutor(max_workers=args.jobs) as ex:
+        print(f"{len(jobs)} net-scenario jobs ({workers} at a time) -> {args.out}")
+        with ThreadPoolExecutor(max_workers=workers) as ex:
             futs = [ex.submit(run_net_job, r, a, s, args.out, args.timeout,
                               args.net_arch, args.net_steps) for r, a, s in jobs]
             for fut in futs:
@@ -465,8 +478,8 @@ def main(argv=None):
             jobs.append((arch, shape, False))
             if not args.single_pod_only:
                 jobs.append((arch, shape, True))
-    print(f"{len(jobs)} jobs -> {args.out}")
-    with ThreadPoolExecutor(max_workers=args.jobs) as ex:
+    print(f"{len(jobs)} jobs ({workers} at a time) -> {args.out}")
+    with ThreadPoolExecutor(max_workers=workers) as ex:
         futs = {ex.submit(run_job, a, s, mp, args.out, args.timeout): (a, s, mp)
                 for a, s, mp in jobs}
         for fut in __import__("concurrent.futures", fromlist=["as_completed"]).as_completed(futs):

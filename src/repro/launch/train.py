@@ -4,8 +4,13 @@
         --nodes 6 --byzantine 1 --attack random --rule trimmed_mean \
         --steps 100 --batch 4 --seq 128
 
-``--reduce`` swaps in the reduced config (CPU-runnable); without it the full
-config is used (requires a real cluster).  Supports checkpoint save/resume.
+``--reduce`` swaps in the reduced config (CPU-runnable); without it the
+published config is used, and every node holds a full replica of it, so it
+needs accelerator memory for ``--nodes`` copies of the parameters and their
+gradients (``chip_smoke.py`` runs qwen3-4b at published widths, with depth
+and vocabulary cut, on one chip).  Supports checkpoint save/resume.  The
+persistent compilation cache goes where ``JAX_COMPILATION_CACHE_DIR`` says,
+else to ``<repo>/.jax_cache`` (`repro.launch.cache`).
 
 Network scenarios (repro.net): ``--net`` routes training through the
 unreliable-network runtime; combine with ``--net-drop 0.2 --net-latency 3
@@ -33,6 +38,7 @@ from repro.configs import get_config
 from repro.core import BridgeConfig, BridgeTrainer, erdos_renyi, replicate
 from repro.core.byzantine import ATTACKS
 from repro.data.tokens import TokenPipeline
+from repro.launch.cache import use_compilation_cache
 from repro.models import api as model_api
 
 
@@ -200,6 +206,7 @@ def main(argv=None):
     ap.add_argument("--trust-no-echo", action="store_true",
                     help="disable the equivocation echo protocol (net path)")
     args = ap.parse_args(argv)
+    use_compilation_cache()
 
     cfg = get_config(args.arch)
     if args.reduce:

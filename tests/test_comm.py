@@ -473,12 +473,12 @@ def test_fused_dequant_trimmed_mean_matches_reference(shape, b):
     mask = jnp.asarray(rng.random(lead) < 0.8)
     mask = mask.at[..., : 2 * b + 1].set(True)
     sv = jnp.asarray(rng.normal(size=shape[:-2] + (d,)), jnp.float32)
-    out = ops.dequant_trimmed_mean(msg.payload, msg.scale, mask, sv, b, block_d=128)
+    out = ops.dequant_trimmed_mean(msg.payload, msg.scale, mask, sv, b, block_d=128, interpret=True)
     exp = ref.dequant_trimmed_mean_ref(msg.payload, msg.scale, mask, sv, b)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=1e-5, atol=1e-5)
     # and the unfused pallas pipeline (dequant kernel -> screen kernel) too
-    staged = ops.trimmed_mean(ops.dequant(msg.payload, msg.scale, block_d=128),
-                              mask, sv, b, block_d=128)
+    staged = ops.trimmed_mean(ops.dequant(msg.payload, msg.scale, block_d=128, interpret=True),
+                              mask, sv, b, block_d=128, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(staged), rtol=1e-5, atol=1e-5)
 
 
@@ -493,7 +493,7 @@ def test_fused_dequant_median_matches_reference(shape):
     mask = jnp.asarray(rng.random(lead) < 0.7)
     mask = mask.at[..., 0].set(True)
     sv = jnp.asarray(rng.normal(size=shape[:-2] + (d,)), jnp.float32)
-    out = ops.dequant_median(msg.payload, msg.scale, mask, sv, block_d=128)
+    out = ops.dequant_median(msg.payload, msg.scale, mask, sv, block_d=128, interpret=True)
     exp = ref.dequant_median_ref(msg.payload, msg.scale, mask, sv)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=1e-5, atol=1e-5)
 

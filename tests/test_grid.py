@@ -233,15 +233,15 @@ def test_batched_kernels_match_per_experiment(e, n, d, b):
     v = jnp.asarray(rng.normal(size=(e, n, d)), jnp.float32)
     mask = jnp.asarray(rng.random((e, n)) < 0.8).at[:, : 2 * b + 1].set(True)
     sv = jnp.asarray(rng.normal(size=(e, d)), jnp.float32)
-    out = ops.trimmed_mean(v, mask, sv, b, block_d=128)
+    out = ops.trimmed_mean(v, mask, sv, b, block_d=128, interpret=True)
     assert out.shape == (e, d)
     exp = ref.trimmed_mean_ref(v, mask, sv, b)  # vmapped oracle
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=1e-5, atol=1e-5)
     for i in range(e):  # and the batch axis changes nothing per slice
-        one = ops.trimmed_mean(v[i], mask[i], sv[i], b, block_d=128)
+        one = ops.trimmed_mean(v[i], mask[i], sv[i], b, block_d=128, interpret=True)
         np.testing.assert_allclose(np.asarray(out[i]), np.asarray(one),
                                    rtol=1e-6, atol=1e-6)
-    om = ops.median(v, mask, block_d=128)
+    om = ops.median(v, mask, block_d=128, interpret=True)
     em = ref.median_ref(v, mask)
     assert om.shape == (e, d)
     np.testing.assert_allclose(np.asarray(om), np.asarray(em), rtol=1e-5, atol=1e-5)

@@ -20,7 +20,7 @@ def test_trimmed_mean_kernel(n, d, b, dtype):
     if int(mask.sum()) < 2 * b + 1:
         mask = jnp.ones((n,), bool)
     sv = jnp.asarray(rng.normal(size=(d,)), dtype)
-    out = ops.trimmed_mean(v, mask, sv, b, block_d=256)
+    out = ops.trimmed_mean(v, mask, sv, b, block_d=256, interpret=True)
     exp = ref.trimmed_mean_ref(v, mask, sv, b)
     tol = 1e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(exp, np.float32),
@@ -33,7 +33,7 @@ def test_median_kernel(n, d, dtype):
     rng = np.random.default_rng(n + d)
     v = jnp.asarray(rng.normal(size=(n, d)), dtype)
     mask = jnp.asarray(rng.random(n) < 0.7).at[0].set(True)
-    out = ops.median(v, mask, block_d=256)
+    out = ops.median(v, mask, block_d=256, interpret=True)
     exp = ref.median_ref(v, mask)
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(exp, np.float32),
                                rtol=1e-5, atol=1e-5)
@@ -43,7 +43,7 @@ def test_median_kernel(n, d, dtype):
 def test_krum_dists_kernel(n, d):
     rng = np.random.default_rng(d)
     v = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
-    out = ops.pairwise_sq_dists(v, block_d=256)
+    out = ops.pairwise_sq_dists(v, block_d=256, interpret=True)
     exp = ref.pairwise_sq_dists_ref(v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=1e-3, atol=1e-2)
 
@@ -61,6 +61,6 @@ def test_trimmed_mean_property(n, d, b, seed):
     v = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
     mask = jnp.ones((n,), bool)
     sv = jnp.asarray(rng.normal(size=(d,)), jnp.float32)
-    out = ops.trimmed_mean(v, mask, sv, b, block_d=128)
+    out = ops.trimmed_mean(v, mask, sv, b, block_d=128, interpret=True)
     exp = ref.trimmed_mean_ref(v, mask, sv, b)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=2e-4, atol=2e-4)

@@ -31,8 +31,9 @@ def test_sharded_gossip_matches_reference():
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core import complete_graph, screen_all, gossip_screen_params
         from repro.core.bridge import stack_flatten
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((4,2), ("data","model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4,2), ("data","model"),
+                             axis_types=(AxisType.Auto,) * 2)
         M = 4
         topo = complete_graph(M, 1)
         adj = jnp.asarray(topo.adjacency)
@@ -62,8 +63,9 @@ def test_sharded_byzantine_attack_screened():
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core import complete_graph, gossip_screen_params
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((8,1), ("data","model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((8,1), ("data","model"),
+                             axis_types=(AxisType.Auto,) * 2)
         M = 8
         topo = complete_graph(M, 2)
         adj = jnp.asarray(topo.adjacency)
@@ -97,8 +99,9 @@ def test_mini_multipod_dryrun_lowers():
         from repro.launch.steps import make_train_step
         from repro.models import api as model_api
 
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2,2,2), ("pod","data","model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2,2,2), ("pod","data","model"),
+                             axis_types=(AxisType.Auto,) * 3)
         nax = ("pod","data")
         cfg = get_config("qwen3-4b").reduced()
         api = model_api.build(cfg)
@@ -135,8 +138,9 @@ def test_serve_step_lowers_with_cache_sharding():
         from repro.launch.steps import make_serve_step
         from repro.models import api as model_api
 
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((4,2), ("data","model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4,2), ("data","model"),
+                             axis_types=(AxisType.Auto,) * 2)
         nax = ("data",)
         cfg = get_config("mistral-nemo-12b").reduced()
         api = model_api.build(cfg)

@@ -381,7 +381,7 @@ def test_gather_screen_kernels_match_staged(topo):
     idx, valid = jnp.asarray(nbr.idx), nbr.valid_dev
     for rule in ("trimmed_mean", "median"):
         ref = screening.screen_views_banked(nbr.gather_rows(w), valid, w, (rule,), 0, 1)
-        out = gather_screen_pallas(w, idx, valid, w, 1, rule=rule, block_d=128)
+        out = gather_screen_pallas(w, idx, valid, w, 1, rule=rule, block_d=128, interpret=True)
         # kernel blocks extract extrema iteratively (VPU-friendly) while the
         # jnp rule sorts — same survivors, different summation order, so the
         # comparison is allclose (the test_kernels convention)
@@ -396,9 +396,9 @@ def test_gather_screen_kernels_match_staged(topo):
                                   rng.uniform(-1, 1, size=(M, s))], -1), jnp.float32)
     staged = dequant_trimmed_mean_pallas(
         jnp.take(q, nbr.safe_idx, axis=0), jnp.take(scale, nbr.safe_idx, axis=0),
-        valid, w, 1, block_d=128)
+        valid, w, 1, block_d=128, interpret=True)
     fused = gather_dequant_screen_pallas(q, scale, idx, valid, w, 1,
-                                         rule="trimmed_mean", block_d=128)
+                                         rule="trimmed_mean", block_d=128, interpret=True)
     np.testing.assert_array_equal(np.asarray(staged), np.asarray(fused))
 
 
