@@ -15,7 +15,6 @@ Two execution paths share the same screening code:
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections.abc import Callable
 from typing import Any, NamedTuple
 
@@ -183,12 +182,28 @@ class BridgeConfig:
 def stack_batches(batch_fn: Callable[[int], Any], num_ticks: int) -> Any:
     """Materialize ``num_ticks`` batches on a new leading axis — the ``xs``
     the scan-over-ticks paths consume.  The single definition shared by
-    `AsyncBridgeTrainer.run_ticks` and the grid engine, so both scan
-    identical inputs (part of their bit-identity contract)."""
-    batches = [batch_fn(i) for i in range(num_ticks)]
-    return jax.tree_util.tree_map(
-        lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]), *batches
-    )
+    `AsyncBridgeTrainer.run_ticks`, the grid engine and `run_chunks`, so all
+    scan identical inputs (part of their bit-identity contract)."""
+    return stack_puts(put_batches([batch_fn(i) for i in range(num_ticks)]))
+
+
+def put_batches(batches: list) -> list:
+    """Every leaf of every batch on the device (``jnp.asarray``): the
+    host-to-device half of `stack_batches`."""
+    return [jax.tree_util.tree_map(jnp.asarray, b) for b in batches]
+
+
+def stack_puts(puts: list) -> Any:
+    """`put_batches`' per-tick batches stacked on a new leading axis: the
+    device half of `stack_batches`."""
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *puts)
+
+
+def host_nbytes(batches: list) -> int:
+    """Bytes of the host (not yet device) leaves of ``batches``: what
+    `put_batches` moves to the device."""
+    return sum(np.asarray(x).nbytes for x in jax.tree_util.tree_leaves(batches)
+               if not isinstance(x, jax.Array))
 
 
 def stack_flatten(params: Any) -> tuple[jax.Array, Callable[[jax.Array], Any]]:
@@ -989,6 +1004,14 @@ class BridgeTrainer:
         flushed), or 64 without one.  Returns ``(final_state, metrics)``
         with ``[T]`` metric streams, bitwise identical to step-at-a-time /
         single-scan execution (pinned by ``tests/test_metrics.py``).
+
+        The loop writes ``jax.profiler`` spans (about a microsecond each
+        when no profiler runs): ``bridge.run_chunks`` (args ``lo``, ``hi``)
+        around the call and, per chunk, ``bridge.put`` (``bytes`` put,
+        ``ticks``), ``bridge.stack`` (``ticks``), ``bridge.dispatch``
+        (``lo``, ``hi``, ``traced``: 1 if the call traced the scan anew),
+        ``bridge.flush`` (writer and events, when given), then one
+        ``bridge.collect`` for the concatenation of the metric chunks.
         """
         mspec = getattr(self.config, "metrics", None)
         if chunk is None:
@@ -1000,28 +1023,38 @@ class BridgeTrainer:
                 f"chunk {chunk} exceeds MetricSpec.capacity {mspec.capacity}: "
                 f"the ring would overwrite unflushed ticks")
         scan_chunk = self._chunk_scan()
-        tree = jax.tree_util.tree_map
+        ann = jax.profiler.TraceAnnotation
         chunks_ms = []
         done = start
-        while done < start + num_steps:
-            hi = min(done + chunk, start + num_steps)
-            xs = stack_batches(lambda i: batch_fn(done + i), hi - done)
-            t_chunk = time.perf_counter()
-            state, ms = scan_chunk(self._cell, state, xs)
-            # host work below overlaps the dispatched device computation:
-            # the writer copies the ring and device_gets on its own thread
-            if writer is not None:
-                writer.flush(state.mets, tag=tag)
-            if events is not None:
-                # dispatch wall, deliberately not block_until_ready — the
-                # overlap IS the feature (grid.chunk events block instead)
-                # `train_tag`, not `tag`: EventLog.emit's first argument IS
-                # the record's "tag" field and fields must not collide
-                events.emit("train.chunk", train_tag=tag, lo=done, hi=hi,
-                            dispatch_s=time.perf_counter() - t_chunk)
-            chunks_ms.append(ms)
-            done = hi
-        metrics = tree(lambda *xs: jnp.concatenate(xs, axis=0), *chunks_ms)
+        with ann("bridge.run_chunks", lo=start, hi=start + num_steps):
+            while done < start + num_steps:
+                hi = min(done + chunk, start + num_steps)
+                batches = [batch_fn(i) for i in range(done, hi)]
+                with ann("bridge.put", bytes=host_nbytes(batches), ticks=hi - done):
+                    puts = put_batches(batches)
+                with ann("bridge.stack", ticks=hi - done):
+                    xs = stack_puts(puts)
+                traces = getattr(self, "chunk_trace_count", 0)
+                with ann("bridge.dispatch", lo=done, hi=hi) as span:
+                    state, ms = scan_chunk(self._cell, state, xs)
+                    span.set_metadata(
+                        traced=int(getattr(self, "chunk_trace_count", 0) > traces))
+                # host work below overlaps the dispatched device computation:
+                # the writer copies the ring and device_gets on its own thread
+                if writer is not None or events is not None:
+                    with ann("bridge.flush"):
+                        if writer is not None:
+                            writer.flush(state.mets, tag=tag)
+                        if events is not None:
+                            # `train_tag`, not `tag`: EventLog.emit's first
+                            # argument IS the record's "tag" field and fields
+                            # must not collide
+                            events.emit("train.chunk", train_tag=tag, lo=done, hi=hi)
+                chunks_ms.append(ms)
+                done = hi
+            with ann("bridge.collect"):
+                metrics = jax.tree_util.tree_map(
+                    lambda *xs: jnp.concatenate(xs, axis=0), *chunks_ms)
         return state, metrics
 
 

@@ -95,32 +95,36 @@ def coordwise_gossip_leaf(
         byz_mask = jnp.zeros((m,), dtype=bool)
 
     def ag_body(x, adj, bm, k, tt):
-        s = _flatten_local(x)  # [m_loc, s]
-        if quantize:
-            q, scale = _quantize_int8(s)
-            gq = lax.all_gather(q, node_axes, axis=0, tiled=True)  # int8 wire
-            gs = lax.all_gather(scale[None], node_axes, axis=0, tiled=True)
-            g = gq.astype(jnp.float32) * gs[:, None]
-        else:
-            g = lax.all_gather(s, node_axes, axis=0, tiled=True)  # [M, s]
+        with jax.named_scope("mesh.gather"):
+            s = _flatten_local(x)  # [m_loc, s]
+            if quantize:
+                q, scale = _quantize_int8(s)
+                gq = lax.all_gather(q, node_axes, axis=0, tiled=True)  # int8 wire
+                gs = lax.all_gather(scale[None], node_axes, axis=0, tiled=True)
+                g = gq.astype(jnp.float32) * gs[:, None]
+            else:
+                g = lax.all_gather(s, node_axes, axis=0, tiled=True)  # [M, s]
         j = lax.axis_index(node_axes)
         g = _inject_attack(g, bm, attack, k, tt, j)
-        y = fn(g, adj[j], g[j], b)  # own-row screening; self row is masked
+        with jax.named_scope("mesh.screen"):
+            y = fn(g, adj[j], g[j], b)  # own-row screening; self row is masked
         # (adjacency has no self loops so g[j] enters only via self_value)
         return y.astype(x.dtype).reshape(x.shape[1:])[None]
 
     def a2a_body(x, adj, bm, k, tt):
-        s = _flatten_local(x)[0]  # [s] (m_loc == 1)
-        size = s.shape[0]
-        pad = (-size) % m
-        sp = jnp.pad(s, (0, pad)).reshape(m, -1)  # [M, chunk]: my coords, split
-        if quantize:
-            q, scale = _quantize_int8(sp)
-            vq = lax.all_to_all(q, node_axes, split_axis=0, concat_axis=0, tiled=True)
-            vs = lax.all_gather(scale[None], node_axes, axis=0, tiled=True)  # [M]
-            vals = vq.astype(jnp.float32) * vs[:, None]
-        else:
-            vals = lax.all_to_all(sp, node_axes, split_axis=0, concat_axis=0, tiled=True)
+        with jax.named_scope("mesh.gather"):
+            s = _flatten_local(x)[0]  # [s] (m_loc == 1)
+            size = s.shape[0]
+            pad = (-size) % m
+            sp = jnp.pad(s, (0, pad)).reshape(m, -1)  # [M, chunk]: my coords, split
+            if quantize:
+                q, scale = _quantize_int8(sp)
+                vq = lax.all_to_all(q, node_axes, split_axis=0, concat_axis=0, tiled=True)
+                vs = lax.all_gather(scale[None], node_axes, axis=0, tiled=True)  # [M]
+                vals = vq.astype(jnp.float32) * vs[:, None]
+            else:
+                vals = lax.all_to_all(sp, node_axes, split_axis=0, concat_axis=0,
+                                      tiled=True)
         # vals[i] = node i's chunk r (r = my node row)
         r = lax.axis_index(node_axes)
         vals = _inject_attack(vals, bm, attack, k, tt, r)
@@ -128,11 +132,14 @@ def coordwise_gossip_leaf(
         # a vmap here materializes [M, M, chunk] masked copies for the sort
         # (M x the a2a buffer — measured 3.5TB/chip on deepseek-v3), while
         # lax.map keeps the peak at [M, chunk] for identical total compute.
-        y_all = lax.map(
-            lambda args: fn(vals, args[0], args[1], b).astype(x.dtype),
-            (adj, vals),
-        )  # [M, chunk]
-        back = lax.all_to_all(y_all, node_axes, split_axis=0, concat_axis=0, tiled=True)
+        with jax.named_scope("mesh.screen"):
+            y_all = lax.map(
+                lambda args: fn(vals, args[0], args[1], b).astype(x.dtype),
+                (adj, vals),
+            )  # [M, chunk]
+        with jax.named_scope("mesh.gather"):
+            back = lax.all_to_all(y_all, node_axes, split_axis=0, concat_axis=0,
+                                  tiled=True)
         # back[c] = my screened chunk c
         out = back.reshape(-1)[:size]
         return out.reshape(x.shape[1:])[None]

@@ -21,7 +21,18 @@ def use_compilation_cache() -> str:
     """Turn on the persistent compilation cache and return its directory.
 
     ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and left
-    alone; otherwise the cache goes to `DEFAULT_DIR`."""
+    alone; otherwise the cache goes to `DEFAULT_DIR`.
+
+    Entries are keyed on the program's metadata too.  JAX's key leaves it
+    out by default, so a program whose ``jax.named_scope``s changed and
+    nothing else would be served the executable compiled before, whose ops
+    carry the old names, and a profile would name them wrongly.  The
+    metadata keeps each op's ``op_name`` (its scopes) but no Python frames,
+    which would tie an entry to the caller's stack and the lines of every
+    file on it: the same program traced from another caller (a ``.lower()``
+    for its HLO text) then finds the same entry."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -48,7 +48,8 @@ def make_train_step(
         def one(p, bt):
             return jax.value_and_grad(lambda pp: api.train_loss(pp, bt, cfg))(p)
 
-        return jax.vmap(one)(params, batch)
+        with jax.named_scope("mesh.grad"):
+            return jax.vmap(one)(params, batch)
 
     def gossip(params, t):
         return gossip_screen_params(

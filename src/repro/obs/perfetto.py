@@ -4,14 +4,15 @@
 Format — a flat list of timestamped events.  This module converts the run
 artifacts the obs layer already writes (``events.jsonl`` host-side event
 records, ``metrics.jsonl`` per-tick scalar rows, ``manifest.json``) into
-that format, so a whole run — chunk dispatches, grid compilation chunks,
+that format, so a whole run — chunk records, grid compilation chunks,
 alerts, divergences, and every metric stream as a counter track — lands on
 one zoomable timeline:
 
 * events carrying a duration (``wall_s`` from blocking grid chunks and run
-  brackets, ``dispatch_s`` from non-blocking ``train.chunk`` dispatches)
-  become complete ("X") slices ending at their record's wall time;
-* all other events become instants ("i") on their source track;
+  brackets) become complete ("X") slices ending at their record's wall time;
+* all other events, ``train.chunk`` among them, become instants ("i") on
+  their source track (the time a chunk's put, stack and dispatch take is in
+  `run_chunks`' ``bridge.*`` profiler spans);
 * metric rows become counter ("C") tracks named ``<tag>/<column>``;
 * the manifest rides in ``otherData`` (what run is this, exactly?).
 
@@ -32,7 +33,7 @@ from typing import Any
 
 # event tags -> the field holding their duration in seconds (everything
 # else renders as an instant)
-_DURATION_FIELDS = ("wall_s", "dispatch_s")
+_DURATION_FIELDS = ("wall_s",)
 # record fields that are identity/timing, not interesting args
 _META_FIELDS = {"tag", "wall", "time"}
 

@@ -119,8 +119,9 @@ def build_stream_cell_step(grad_fn, spec: BlockSpec, adjacency, rules, attacks, 
         with jax.named_scope("stream.grad"):
             losses, grads = jax.vmap(grad_fn)(state.params, batch)
         rho = cell_step_size(cell, state.t)
-        x_mats = spec.leaf_mats(state.params)
-        g_mats = spec.leaf_mats(grads)
+        with jax.named_scope("stream.layout"):
+            x_mats = spec.leaf_mats(state.params)
+            g_mats = spec.leaf_mats(grads)
         hm = ~cell.byz_mask
         hcnt = jnp.sum(hm)
 
@@ -295,7 +296,8 @@ def build_stream_cell_step(grad_fn, spec: BlockSpec, adjacency, rules, attacks, 
             comm_out.append(comm_leaf)
             vals_out.append(vals_leaf)
 
-        new_params = spec.unflatten(mats_out)
+        with jax.named_scope("stream.layout"):
+            new_params = spec.unflatten(mats_out)
         new_comm = None if state.comm is None else tuple(comm_out)
         new_net = state.net
         if channel is not None:

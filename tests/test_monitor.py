@@ -45,7 +45,7 @@ def run_dir(tmp_path):
     _write_jsonl(os.path.join(d, "events.jsonl"), [
         {"tag": "run.start", "wall": 0.0, "time": 1.0},
         {"tag": "train.chunk", "wall": 0.25, "time": 1.2, "train_tag": "train",
-         "lo": 0, "hi": 2, "dispatch_s": 0.2},
+         "lo": 0, "hi": 2},
     ])
     return d
 
@@ -206,9 +206,10 @@ def test_chrome_trace_golden():
     events = [
         {"tag": "run.start", "wall": 0.0, "time": 1.0, "steps": 4},
         {"tag": "train.chunk", "wall": 0.5, "time": 1.5, "train_tag": "train",
-         "lo": 0, "hi": 2, "dispatch_s": 0.4},
+         "lo": 0, "hi": 2},
         {"tag": "obs.alert", "wall": 0.6, "time": 1.6, "kind": "divergence",
          "stream": "train", "tick": 2},
+        {"tag": "run.end", "wall": 0.7, "time": 1.7, "steps": 4, "wall_s": 0.4},
     ]
     rows = [{"tag": "train", "wall": 0.45, "tick": 1, "loss": 1.5,
              "stale_p50": None}]
@@ -221,15 +222,18 @@ def test_chrome_trace_golden():
     assert metas[0]["args"] == {"name": "repro"}
     assert [(m["tid"], m["args"]["name"]) for m in metas[1:]] == [
         (1, "run"), (2, "train/train"), (3, "alerts")]
-    # the dispatch becomes an X slice ENDING at its wall time
+    # a record with a duration becomes an X slice ENDING at its wall time
     x = next(e for e in te if e["ph"] == "X")
-    assert x["name"] == "train.chunk"
-    assert x["ts"] == pytest.approx((0.5 - 0.4) * 1e6)
+    assert x["name"] == "run.end" and x["tid"] == 1
+    assert x["ts"] == pytest.approx((0.7 - 0.4) * 1e6)
     assert x["dur"] == pytest.approx(0.4 * 1e6)
-    assert x["args"]["lo"] == 0 and x["args"]["hi"] == 2
-    # run.start and the alert are instants on their own tracks
+    # a chunk record is an instant on its stream's track; run.start and the
+    # alert are instants on their own tracks
     instants = [e for e in te if e["ph"] == "i"]
-    assert {e["name"] for e in instants} == {"run.start", "obs.alert"}
+    assert {e["name"] for e in instants} == {"run.start", "train.chunk", "obs.alert"}
+    chunk = next(e for e in instants if e["name"] == "train.chunk")
+    assert chunk["tid"] == 2 and chunk["ts"] == pytest.approx(0.5 * 1e6)
+    assert chunk["args"]["lo"] == 0 and chunk["args"]["hi"] == 2
     # the metric row is one counter per non-null, non-tick column
     counters = [e for e in te if e["ph"] == "C"]
     assert [(c["name"], c["args"]) for c in counters] == [
