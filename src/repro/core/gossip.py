@@ -12,13 +12,20 @@ Two collective schedules (the subject of §Perf iteration 1):
 
 * ``all_gather`` — paper-faithful broadcast: every chip all-gathers all M
   node values of its shard (M*P bytes on the wire per step) and screens its
-  own node's row.
+  own node's row.  The gathered leaf keeps its own shape ``[M, ...]`` and
+  the rule runs on it as it is: a flattened ``[M, s]`` view costs a
+  relayout copy on the TPU's tiled layouts and puts the M node rows on the
+  sublanes of the same tiles.
 * ``all_to_all`` — beyond-paper coordinate-partitioned schedule: each chip's
   shard is split into M coordinate chunks; a first all_to_all transposes
   (node, chunk) ownership, every chip screens its chunk **for all M
   receivers**, a second all_to_all transposes back (2*P bytes on the wire).
   Valid because BRIDGE-T/M are coordinate-separable (Sec. III: "the
   calculation of y_j(t) has to be carried out in a coordinate-wise manner").
+
+Both schedules screen with the same rules (`_COORD_RULES`): the trimmed mean
+without the XLA-CPU anchor of `screening.trimmed_mean` (on a whole gathered
+leaf it would run the sort network a second time), bitwise its output.
 
 Vector rules (BRIDGE-K/B) need global inter-replica distances; those are
 computed with pure-GSPMD reductions (per-leaf partial Gram matrices that XLA
@@ -37,23 +44,23 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import screening
 
-_COORD_RULES = ("trimmed_mean", "median", "mean")
-
-
-def _flatten_local(x):
-    return x.reshape(x.shape[0], -1)
+_COORD_RULES = {
+    "trimmed_mean": screening.trimmed_mean_unanchored,
+    "median": screening.coordinate_median,
+    "mean": screening.mean,
+}
 
 
 def _inject_attack(vals, byz_mask, attack, key, t, node_index):
-    """Substitute Byzantine rows of the gathered value matrix [M, s]."""
+    """Substitute Byzantine rows of the gathered values [M, ...]."""
     if attack == "none" or byz_mask is None:
         return vals
     if attack == "random":
         k = jax.random.fold_in(jax.random.fold_in(key, t), node_index)
         noise = 10.0 * jax.random.normal(k, vals.shape, vals.dtype)
-        return jnp.where(byz_mask[:, None], noise, vals)
+        return jnp.where(screening.per_row(byz_mask, vals), noise, vals)
     if attack == "sign_flip":
-        return jnp.where(byz_mask[:, None], -4.0 * vals, vals)
+        return jnp.where(screening.per_row(byz_mask, vals), -4.0 * vals, vals)
     raise ValueError(f"attack {attack!r} not supported on the sharded path")
 
 
@@ -85,9 +92,8 @@ def coordwise_gossip_leaf(
     quantize: bool = False,
 ) -> jax.Array:
     """Screen one [M, ...] parameter leaf with a coordinate-wise rule."""
-    assert rule in _COORD_RULES, rule
+    fn = _COORD_RULES[rule]
     m = leaf.shape[0]
-    fn = screening.get_rule(rule)
     if key is None:
         key = jax.random.PRNGKey(0)
     t = jnp.asarray(t, jnp.int32)
@@ -96,24 +102,23 @@ def coordwise_gossip_leaf(
 
     def ag_body(x, adj, bm, k, tt):
         with jax.named_scope("mesh.gather"):
-            s = _flatten_local(x)  # [m_loc, s]
             if quantize:
-                q, scale = _quantize_int8(s)
+                q, scale = _quantize_int8(x)
                 gq = lax.all_gather(q, node_axes, axis=0, tiled=True)  # int8 wire
                 gs = lax.all_gather(scale[None], node_axes, axis=0, tiled=True)
-                g = gq.astype(jnp.float32) * gs[:, None]
+                g = gq.astype(jnp.float32) * screening.per_row(gs, gq)
             else:
-                g = lax.all_gather(s, node_axes, axis=0, tiled=True)  # [M, s]
+                g = lax.all_gather(x, node_axes, axis=0, tiled=True)  # [M, ...]
         j = lax.axis_index(node_axes)
         g = _inject_attack(g, bm, attack, k, tt, j)
         with jax.named_scope("mesh.screen"):
             y = fn(g, adj[j], g[j], b)  # own-row screening; self row is masked
         # (adjacency has no self loops so g[j] enters only via self_value)
-        return y.astype(x.dtype).reshape(x.shape[1:])[None]
+        return y.astype(x.dtype)[None]
 
     def a2a_body(x, adj, bm, k, tt):
         with jax.named_scope("mesh.gather"):
-            s = _flatten_local(x)[0]  # [s] (m_loc == 1)
+            s = x.reshape(-1)  # [s] (m_loc == 1)
             size = s.shape[0]
             pad = (-size) % m
             sp = jnp.pad(s, (0, pad)).reshape(m, -1)  # [M, chunk]: my coords, split
@@ -121,7 +126,7 @@ def coordwise_gossip_leaf(
                 q, scale = _quantize_int8(sp)
                 vq = lax.all_to_all(q, node_axes, split_axis=0, concat_axis=0, tiled=True)
                 vs = lax.all_gather(scale[None], node_axes, axis=0, tiled=True)  # [M]
-                vals = vq.astype(jnp.float32) * vs[:, None]
+                vals = vq.astype(jnp.float32) * screening.per_row(vs, vq)
             else:
                 vals = lax.all_to_all(sp, node_axes, split_axis=0, concat_axis=0,
                                       tiled=True)

@@ -8,7 +8,11 @@ where ``values`` is ``[n, d]`` — the messages received from (up to) ``n``
 potential in-neighbors, ``mask`` is ``[n]`` bool marking which rows are real
 neighbors (graphs have varying degree; rows with ``mask==False`` are ignored),
 ``self_value`` is ``[d]`` — the node's own iterate, and ``b`` is the maximum
-number of Byzantine nodes to tolerate.
+number of Byzantine nodes to tolerate.  The coordinate-wise rules
+(`trimmed_mean`, `trimmed_mean_unanchored`, `coordinate_median`, `mean`) take
+``values [n, ...]`` and ``self_value [...]`` of any trailing shape, so the
+mesh path screens a gathered parameter leaf in its own layout; for ``[n, d]``
+their ops are exactly the 2-D ones.
 
 These are the pure-jnp reference implementations; `repro.kernels` provides the
 Pallas TPU realizations of the coordinate-wise hot loops, and `gossip.py`
@@ -136,12 +140,12 @@ def _batcher_pairs(n: int) -> tuple[tuple[int, int], ...]:
 
 
 def sort_rows(values: jax.Array) -> jax.Array:
-    """Ascending sort of ``values [n, d]`` along the (small) neighbor axis.
+    """Ascending sort of ``values [n, ...]`` along the (small) neighbor axis.
 
     XLA's CPU sort lowers to a scalar per-column loop — ~1us per 12-element
     column, which makes screening the step's hot spot.  For the neighbor
     counts BRIDGE actually sees (n <= a few dozen) a Batcher odd-even merge
-    network of element-wise ``minimum``/``maximum`` over whole [d] rows
+    network of element-wise ``minimum``/``maximum`` over whole rows
     vectorizes instead, an order of magnitude faster, and produces the exact
     sorted array (values are unique-by-rank, so the output is identical to
     ``jnp.sort``).  Large n falls back to ``jnp.sort``.  NaNs must already be
@@ -150,17 +154,75 @@ def sort_rows(values: jax.Array) -> jax.Array:
     n = values.shape[0]
     if n > 64:
         return jnp.sort(values, axis=0)
-    rows = list(values)
-    for a, b in _batcher_pairs(n):
+    return jnp.stack(_sort_network(list(values)))
+
+
+def _sort_network(rows: list) -> list:
+    """The Batcher compare-exchange network of `sort_rows` on a list of
+    equally shaped rows: the rows, sorted element-wise, lowest first."""
+    for a, b in _batcher_pairs(len(rows)):
         lo = jnp.minimum(rows[a], rows[b])
         hi = jnp.maximum(rows[a], rows[b])
         rows[a], rows[b] = lo, hi
-    return jnp.stack(rows)
+    return rows
 
 
 # ---------------------------------------------------------------------------
 # Coordinate-wise rules (BRIDGE-T, BRIDGE-M)
 # ---------------------------------------------------------------------------
+
+
+def per_row(x: jax.Array, values: jax.Array) -> jax.Array:
+    """``x [n]`` broadcast against ``values [n, ...]``: ``x[:, None]`` for
+    ``[n, d]`` values (the very op the 2-D rules always used), one more
+    ``None`` for each further trailing axis."""
+    return x[(slice(None),) + (None,) * (values.ndim - 1)]
+
+
+def _kept(rank, count: jax.Array, b_eff: jax.Array) -> jax.Array:
+    """BRIDGE-T's rank window: rank ``rank`` of the ascending order is kept
+    iff it lies in ``[b_eff, |N_j| - b_eff)``."""
+    return (rank >= b_eff) & (rank < count - b_eff)
+
+
+def _trim_window(values: jax.Array, mask: jax.Array, self_value: jax.Array, b):
+    """BRIDGE-T on the whole ``values [n, ...]`` array: sanitize, mask, sort,
+    keep the window's ranks, add the node's own value, divide by
+    ``|N_j| - 2b + 1``.  Returns ``(y, masked, order, count, b_eff)``."""
+    n = values.shape[0]
+    count = jnp.sum(mask)  # |N_j|, traced scalar
+    b_eff = effective_trim(b, count)  # == b whenever count >= 2b + 1
+    masked = jnp.where(per_row(mask, values), _sanitize(values), _MASKED)
+    order = sort_rows(masked)  # ascending; masked at the end
+    keep = _kept(per_row(jnp.arange(n), values), count, b_eff)
+    total = sum_rows(jnp.where(keep, order, 0.0)) + self_value
+    y = total / (count - 2 * b_eff + 1).astype(values.dtype)
+    return y, masked, order, count, b_eff
+
+
+def trimmed_mean_unanchored(values: jax.Array, mask: jax.Array, self_value: jax.Array,
+                            b: int) -> jax.Array:
+    """BRIDGE-T without `trimmed_mean`'s XLA-CPU anchor, for values of any
+    trailing shape: the mesh screen (`repro.core.gossip`) runs it on whole
+    gathered leaves ``[M, ...]``.
+
+    The same per-coordinate ops in the same order as `_trim_window`, so the
+    output is bitwise `trimmed_mean`'s, but row by row: each row is masked
+    by its own scalar, sorted by `_sort_network`, and kept or dropped by its
+    rank's scalar, with no ``[n, ...]`` array in between.  On the TPU the
+    whole-array form is four passes over ``[n, ...]`` (the masked copy, the
+    network, the kept ranks' stack, the sum), this one pass.  Above 64 rows
+    it is the whole-array form, which sorts with ``jnp.sort``."""
+    n = values.shape[0]
+    if n > 64:
+        return _trim_window(values, mask, self_value, b)[0]
+    count = jnp.sum(mask)
+    b_eff = effective_trim(b, count)
+    order = _sort_network([jnp.where(mask[i], _sanitize(values[i]), _MASKED)
+                           for i in range(n)])
+    kept = [jnp.where(_kept(i, count, b_eff), row, 0.0) for i, row in enumerate(order)]
+    total = functools.reduce(jnp.add, kept) + self_value  # the chain of `sum_rows`
+    return total / (count - 2 * b_eff + 1).astype(values.dtype)
 
 
 def trimmed_mean(values: jax.Array, mask: jax.Array, self_value: jax.Array, b: int) -> jax.Array:
@@ -170,21 +232,15 @@ def trimmed_mean(values: jax.Array, mask: jax.Array, self_value: jax.Array, b: i
     average the survivors together with the node's own value, with divisor
     ``|N_j| - 2b + 1``.
     """
-    n = values.shape[0]
-    count = jnp.sum(mask)  # |N_j|, traced scalar
-    b_eff = effective_trim(b, count)  # == b whenever count >= 2b + 1
-    masked = jnp.where(mask[:, None], _sanitize(values), _MASKED)
-    order = sort_rows(masked)  # ascending; masked at the end
-    idx = jnp.arange(n)[:, None]
-    keep = (idx >= b_eff) & (idx < count - b_eff)  # ranks [b_eff, |N_j| - b_eff)
-    total = sum_rows(jnp.where(keep, order, 0.0)) + self_value
-    y = total / (count - 2 * b_eff + 1).astype(values.dtype)
+    y, _, order, _, _ = _trim_window(values, mask, self_value, b)
     # XLA CPU re-computes the fused sort network per consumer; a scalar
     # full-reduce consumer forces `order` to materialize once (~3x on
     # [128, 16, 64]).  min (not sum: huge payloads overflow a sum to
     # inf - inf = NaN) of NaN-sanitized input is never NaN, so the select is
     # the identity bitwise, but the compare can't be constant-folded — that
-    # is what keeps the reduce alive.
+    # is what keeps the reduce alive.  On the TPU it is a second pass of the
+    # network over all of ``values`` (22 ms a step on the mesh path's
+    # gathered leaves), which `trimmed_mean_unanchored` does without.
     anchor = jnp.min(order)
     return jnp.where(anchor == anchor, y, jnp.zeros_like(y))
 
@@ -195,14 +251,14 @@ def coordinate_median(values: jax.Array, mask: jax.Array, self_value: jax.Array,
     Even cardinalities average the two middle order statistics.
     """
     del b  # median needs no explicit knowledge of b (Sec. III)
-    stacked = jnp.concatenate([values, self_value[None, :]], axis=0)
+    stacked = jnp.concatenate([values, self_value[None]], axis=0)
     full_mask = jnp.concatenate([mask, jnp.ones((1,), dtype=bool)], axis=0)
     n1 = stacked.shape[0]
     count = jnp.sum(full_mask)
-    order = sort_rows(jnp.where(full_mask[:, None], _sanitize(stacked), _MASKED))
+    order = sort_rows(jnp.where(per_row(full_mask, stacked), _sanitize(stacked), _MASKED))
     lo = (count - 1) // 2
     hi = count // 2
-    idx = jnp.arange(n1)[:, None]
+    idx = per_row(jnp.arange(n1), stacked)
     pick_lo = jnp.sum(jnp.where(idx == lo, order, 0.0), axis=0)
     pick_hi = jnp.sum(jnp.where(idx == hi, order, 0.0), axis=0)
     return fence(0.5 * (pick_lo + pick_hi))
@@ -332,7 +388,7 @@ def mean(values: jax.Array, mask: jax.Array, self_value: jax.Array, b: int = 0) 
     N_j ∪ {j}).  The b=0 baseline the paper's Figures 1-2 compare against."""
     del b
     count = jnp.sum(mask)
-    total = sum_rows(jnp.where(mask[:, None], values, 0.0)) + self_value
+    total = sum_rows(jnp.where(per_row(mask, values), values, 0.0)) + self_value
     return total / (count + 1).astype(values.dtype)
 
 
@@ -488,15 +544,7 @@ RULES: dict[str, Callable] = {
 
 
 def trimmed_mean_with_decisions(values, mask, self_value, b, *, decide_stride=1):
-    n = values.shape[0]
-    count = jnp.sum(mask)
-    b_eff = effective_trim(b, count)
-    masked = jnp.where(mask[:, None], _sanitize(values), _MASKED)
-    order = sort_rows(masked)
-    idx = jnp.arange(n)[:, None]
-    keep = (idx >= b_eff) & (idx < count - b_eff)
-    total = sum_rows(jnp.where(keep, order, 0.0)) + self_value
-    y = total / (count - 2 * b_eff + 1).astype(values.dtype)
+    y, masked, order, count, b_eff = _trim_window(values, mask, self_value, b)
     # kept iff the value lies within the kept-rank boundary order statistics
     # (ties at the boundary count as kept — conservative for the counters).
     # The picks are dynamic row gathers, not masked reductions: on the

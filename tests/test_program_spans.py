@@ -8,11 +8,15 @@
 * splitting the put from the stack leaves the loop's outputs bitwise those
   of the single ``stack_batches`` expression it replaced;
 * the mesh step's compiled HLO carries ``mesh.grad`` / ``mesh.gather`` /
-  ``mesh.screen`` in its ``op_name``s, and the streaming tick's reshapes
-  of the parameter leaves lower under ``stream.layout``.
+  ``mesh.screen`` in its ``op_name``s, with no ``min`` reduce under
+  ``mesh.screen`` and no leaf reshape under ``mesh.gather``, and the
+  streaming tick's reshapes of the parameter leaves lower under
+  ``stream.layout``.
 """
+import functools
 import glob
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -152,7 +156,10 @@ def test_run_chunks_bitwise_the_loop_before_the_split(cls):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_mesh_step_hlo_carries_the_mesh_scopes():
+@functools.cache
+def mesh_step_programs():
+    """The BRIDGE train step of a 1-layer qwen3-4b on a (1, 1) mesh, 3 nodes:
+    (its compiled HLO text, its lowered program with locations)."""
     from jax.sharding import AxisType
 
     from repro.configs import get_config
@@ -175,8 +182,32 @@ def test_mesh_step_hlo_carries_the_mesh_scopes():
     in_sh = (sharding.named(mesh, pspecs),
              sharding.named(mesh, sharding.train_batch_specs(batch, nax)), None)
     with mesh:
-        txt = jax.jit(step, in_shardings=in_sh).lower(
-            pshapes, batch, jax.ShapeDtypeStruct((), jnp.float32)).compile().as_text()
+        lowered = jax.jit(step, in_shardings=in_sh).lower(
+            pshapes, batch, jax.ShapeDtypeStruct((), jnp.float32))
+        return lowered.compile().as_text(), lowered.as_text(debug_info=True)
+
+
+def test_mesh_step_hlo_carries_the_mesh_scopes():
+    txt, _ = mesh_step_programs()
+    for scope in ("mesh.grad", "mesh.gather", "mesh.screen"):
+        assert f"/{scope}/" in txt, scope
+
+
+def test_mesh_screen_is_one_pass_on_the_leaf_layout():
+    """The mesh screen runs the trimmed mean without the XLA-CPU anchor (no
+    ``min`` reduce under ``mesh.screen``) on each gathered leaf in its own
+    shape (no reshape of a leaf under ``mesh.gather``).  The CPU compiles
+    reshapes to bitcasts, which carry no metadata, so the reshapes are
+    looked for in the lowered program's locations as well."""
+    txt, lowered = mesh_step_programs()
+    screen = [line for line in txt.splitlines() if "/mesh.screen/" in line]
+    assert screen
+    assert not [line for line in screen
+                if "mesh.screen/reduce_min" in line or "minimum_reduce" in line]
+    gather = [line for line in txt.splitlines() if "/mesh.gather/" in line]
+    assert not [line for line in gather
+                if re.search(r"= \S+ (reshape|bitcast|copy)\(", line)]
+    assert lowered.count("mesh.gather/reshape") == 0
     for scope in ("mesh.grad", "mesh.gather", "mesh.screen"):
         assert f"/{scope}/" in txt, scope
 

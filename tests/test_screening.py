@@ -196,3 +196,81 @@ def test_hull_invariant_under_extreme_attack():
     for rule in ("trimmed_mean", "median"):
         y = np.asarray(screen_all(w, jnp.asarray(topo.adjacency), rule=rule, b=b))[honest]
         assert (y >= hv.min(0) - 1e-4).all() and (y <= hv.max(0) + 1e-4).all()
+
+
+# The 2-D forms of the coordinate-wise rules as they were before the rules
+# took values of any trailing shape.  The flat, stream, grid and net paths
+# call the rules on ``[n, d]`` values, and their programs must keep these ops.
+def _sort_rows_2d(values):
+    rows = list(values)
+    for a, b in screening._batcher_pairs(values.shape[0]):
+        rows[a], rows[b] = jnp.minimum(rows[a], rows[b]), jnp.maximum(rows[a], rows[b])
+    return jnp.stack(rows)
+
+
+def _trimmed_mean_2d(values, mask, self_value, b):
+    n = values.shape[0]
+    count = jnp.sum(mask)
+    b_eff = screening.effective_trim(b, count)
+    masked = jnp.where(mask[:, None], screening._sanitize(values), screening._MASKED)
+    order = _sort_rows_2d(masked)
+    idx = jnp.arange(n)[:, None]
+    keep = (idx >= b_eff) & (idx < count - b_eff)
+    total = screening.sum_rows(jnp.where(keep, order, 0.0)) + self_value
+    y = total / (count - 2 * b_eff + 1).astype(values.dtype)
+    anchor = jnp.min(order)
+    return jnp.where(anchor == anchor, y, jnp.zeros_like(y))
+
+
+def _median_2d(values, mask, self_value, b):
+    stacked = jnp.concatenate([values, self_value[None, :]], axis=0)
+    full_mask = jnp.concatenate([mask, jnp.ones((1,), dtype=bool)], axis=0)
+    count = jnp.sum(full_mask)
+    order = _sort_rows_2d(jnp.where(full_mask[:, None], screening._sanitize(stacked),
+                                    screening._MASKED))
+    lo, hi = (count - 1) // 2, count // 2
+    idx = jnp.arange(stacked.shape[0])[:, None]
+    pick_lo = jnp.sum(jnp.where(idx == lo, order, 0.0), axis=0)
+    pick_hi = jnp.sum(jnp.where(idx == hi, order, 0.0), axis=0)
+    return screening.fence(0.5 * (pick_lo + pick_hi))
+
+
+def _mean_2d(values, mask, self_value, b):
+    count = jnp.sum(mask)
+    total = screening.sum_rows(jnp.where(mask[:, None], values, 0.0)) + self_value
+    return total / (count + 1).astype(values.dtype)
+
+
+def _trimmed_mean_with_decisions_2d(values, mask, self_value, b):
+    n = values.shape[0]
+    count = jnp.sum(mask)
+    b_eff = screening.effective_trim(b, count)
+    masked = jnp.where(mask[:, None], screening._sanitize(values), screening._MASKED)
+    order = _sort_rows_2d(masked)
+    idx = jnp.arange(n)[:, None]
+    keep = (idx >= b_eff) & (idx < count - b_eff)
+    total = screening.sum_rows(jnp.where(keep, order, 0.0)) + self_value
+    y = total / (count - 2 * b_eff + 1).astype(values.dtype)
+    lo = jax.lax.dynamic_index_in_dim(order, b_eff, 0, keepdims=False)
+    hi = jax.lax.dynamic_index_in_dim(
+        order, jnp.maximum(count - b_eff - 1, b_eff), 0, keepdims=False)
+    kept = (masked[:, ::1] >= lo[None, ::1]) & (masked[:, ::1] <= hi[None, ::1])
+    trim = jnp.mean((mask[:, None] & ~kept).astype(jnp.float32), axis=1)
+    anchor = jnp.min(order)
+    return y, jnp.where(anchor == anchor, trim, jnp.zeros_like(trim))
+
+
+@pytest.mark.parametrize("rule,before", [
+    (screening.trimmed_mean, _trimmed_mean_2d),
+    (screening.coordinate_median, _median_2d),
+    (screening.mean, _mean_2d),
+    (screening.trimmed_mean_with_decisions, _trimmed_mean_with_decisions_2d),
+])
+def test_2d_rules_keep_their_ops(rule, before):
+    """On ``[n, d]`` values each coordinate-wise rule traces to exactly the
+    program of its 2-D form, anchor and fence included."""
+    args = (jax.ShapeDtypeStruct((7, 13), jnp.float32), jax.ShapeDtypeStruct((7,), bool),
+            jax.ShapeDtypeStruct((13,), jnp.float32))
+    got = jax.make_jaxpr(lambda v, m, s: rule(v, m, s, 2))(*args)
+    want = jax.make_jaxpr(lambda v, m, s: before(v, m, s, 2))(*args)
+    assert str(got) == str(want)

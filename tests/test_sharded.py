@@ -57,6 +57,63 @@ def test_sharded_gossip_matches_reference():
     assert "OK" in out
 
 
+@pytest.mark.parametrize("rule", ["trimmed_mean", "median", "mean"])
+def test_mesh_screen_bitwise_the_flat_screen(rule):
+    """The mesh screen runs on each gathered leaf in its own shape (trailing
+    sizes no multiple of 8 or 128); per coordinate it is exactly
+    `screen_all` on the `stack_flatten` matrix of the same pytree, on both
+    schedules, with and without a sign-flip attack.  With the int8 wire
+    codec the reference screens the dequantized values (one scale per node
+    and leaf), within the sharded reference test's 1e-5."""
+    out = _run(f"""
+        import functools
+        import numpy as np, jax, jax.numpy as jnp
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+        from repro.core import complete_graph, screen_all, gossip_screen_params
+        from repro.core.bridge import stack_flatten
+        mesh = jax.make_mesh((4, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+        M, rule = 4, {rule!r}
+        adj = jnp.asarray(complete_graph(M, 1).adjacency)
+        byz = np.zeros(M, bool)
+        byz[1] = True
+        rng = np.random.default_rng(0)
+        params = {{"a": rng.normal(size=(M, 6, 8)), "b": rng.normal(size=(M, 10)),
+                   "c": rng.normal(size=(M, 3, 5, 7))}}
+        params = {{k: v.astype(np.float32) for k, v in params.items()}}
+        specs = {{"a": P("data", None, "model"), "b": P("data", "model"),
+                  "c": P("data", None, None, "model")}}
+        sharded = {{k: jax.device_put(v, NamedSharding(mesh, specs[k]))
+                    for k, v in params.items()}}
+
+        @functools.partial(jax.jit, static_argnums=(1, 2, 3))
+        def screen(p, sched, attack, quantize):
+            return gossip_screen_params(
+                p, specs, mesh=mesh, node_axes="data", rule=rule, b=1, adjacency=adj,
+                schedule=sched, byz_mask=jnp.asarray(byz), attack=attack, quantize=quantize)
+
+        def dequant(x):
+            amax = np.abs(x).max(axis=tuple(range(1, x.ndim)), keepdims=True)
+            scale = np.maximum(amax, np.float32(1e-12)) / np.float32(127)
+            return np.clip(np.round(x / scale), -127, 127) * scale
+
+        for quantize in (False, True):
+            seen = {{k: dequant(v) if quantize else v for k, v in params.items()}}
+            w, unflatten = stack_flatten(seen)
+            for attack in ("none", "sign_flip"):
+                wa = jnp.where(byz[:, None], -4.0 * w, w) if attack == "sign_flip" else w
+                ref = unflatten(screen_all(wa, adj, rule=rule, b=1))
+                for sched in ("all_gather", "all_to_all"):
+                    got = screen(sharded, sched, attack, quantize)
+                    assert jax.tree.map(jnp.shape, got) == jax.tree.map(jnp.shape, ref)
+                    err = max(float(jnp.max(jnp.abs(x - y))) for x, y in
+                              zip(jax.tree.leaves(got), jax.tree.leaves(ref), strict=True))
+                    ok = err < 1e-5 if quantize else err == 0.0
+                    assert ok, (rule, sched, attack, quantize, err)
+        print("OK")
+    """, devices=4)
+    assert "OK" in out
+
+
 def test_sharded_byzantine_attack_screened():
     """Random attack rows injected on the sharded path are screened out."""
     out = _run("""
