@@ -3,6 +3,9 @@
 
     python3 bench/calibrate.py --workload <name> --seeds 1,2,... --control-seeds 1,2,3
 
+Host memory grows from seed to seed in one process: at the stream cells'
+size, run one seed a process (a shell loop over ``--seeds <s>``).
+
 Not part of a benchmark run.  For each seed it drives the cell's program
 through the ticks the reference follows (the set-up of a run, no window)
 and compares it with the float32 reference, as a run does: the lower
@@ -10,10 +13,12 @@ reading of each number is the largest over these sound runs.  For each
 control seed it also reads:
 
 * the control: the configuration's precision lowered one step (float32 ->
-  bfloat16), through the program's own path where it has one (the dense
-  family's ``dtype``), else the reference in bfloat16 in the program's place;
+  bfloat16), through the program's own path where it has one (a model
+  module with ``program_config(cfg, dtype)``), else the reference in
+  bfloat16 in the program's place;
 * the faults, planted in the reference put in the program's place: half of
-  each node's batch left out (the mean taken over the rest), and, on a path
+  each node's batch left out (the mean taken over the rest; half of the
+  sequence where a node's batch is one row), and, on a path
   whose nodes exchange between chips, the exchange left out.  A state left
   unchanged reads ``change_gap`` = 1 by construction and needs no run.
 
@@ -30,10 +35,21 @@ sys.path[:0] = [os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                 os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")]
 
 
+def half_rows(a, axis):
+    """``a`` cut to the first half of its rows along ``axis``, the batch
+    axis, or, where the batch is one row, to the first half of that row's
+    sequence (the next axis: a row of S + 1 tokens keeps S / 2 + 1, so the
+    loss is the mean over the first S / 2 positions)."""
+    if a.shape[axis] == 1:
+        return a[(slice(None),) * (axis + 1) + (slice(a.shape[axis + 1] // 2 + 1),)]
+    return a[(slice(None),) * axis + (slice(a.shape[axis] // 2),)]
+
+
 def half_batches(batches):
+    """Each node's batch halved by `half_rows` (axis 1, after the node axis)."""
     import jax
 
-    return [jax.tree_util.tree_map(lambda a: a[:, : a.shape[1] // 2], b) for b in batches]
+    return [jax.tree_util.tree_map(lambda a: half_rows(a, 1), b) for b in batches]
 
 
 def calibrate(work: dict, cfg: dict, traffic: dict, seeds, control_seeds, cell_cls=None,
@@ -45,7 +61,7 @@ def calibrate(work: dict, cfg: dict, traffic: dict, seeds, control_seeds, cell_c
 
     import jax
 
-    from bench import check, run
+    from bench import check, models, run
     from bench.reference import bridge as ref_bridge
 
     Cell = cell_cls or importlib.import_module(f"bench.paths.{traffic['path']}").Cell
@@ -66,7 +82,7 @@ def calibrate(work: dict, cfg: dict, traffic: dict, seeds, control_seeds, cell_c
             continue
         params0 = spec["params0"]
         t = time.perf_counter()
-        if cfg["model"] == "linear":  # no lower-precision path in the program
+        if not hasattr(models.of(cfg), "program_config"):  # no lower-precision path
             losses, change, _ = check.reference_readings(spec, params0(), dtype="bfloat16")
         else:
             cell = Cell(cfg, traffic, seed, dtype="bfloat16")

@@ -3,15 +3,18 @@ scan of ``ticks_per_call`` ticks per call, batches stacked by the trainer
 from the host arrays the benchmark made in set-up."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import numpy as np
 
-from bench import generators, weights
+from bench import generators, models, weights
 
 
 class ChunkedCell:
-    """The shared driver; a path module supplies the trainer, the batches,
-    the program's weight layout and the reference's gradient."""
+    """The shared driver; a path module supplies the trainer, the batches
+    and the program's weight layout, and the model family (`bench.models`)
+    the reference's gradient."""
 
     check_calls = 1  # the first call's ticks are the ones the reference follows
     sparse = False  # the sparse [M, K] neighbour table (`BridgeConfig.sparse`)
@@ -35,9 +38,6 @@ class ChunkedCell:
         raise NotImplementedError
 
     def program_layout(self):
-        raise NotImplementedError
-
-    def reference_grad(self):
         raise NotImplementedError
 
     # -- the run ----------------------------------------------------------
@@ -83,9 +83,9 @@ class ChunkedCell:
     def reference_spec(self) -> dict:
         honest = ~self.byz
         return {"batches": self.batches[:self.ticks_per_call * self.check_calls],
-                "node_grad": self.reference_grad(), "adj": self.adj, "byz": self.byz,
-                "b": self.b, "attack": self.traffic["attack"], "lam": self.traffic["lam"],
-                "t0": self.traffic["t0"], "honest": honest,
+                "node_grad": functools.partial(models.of(self.cfg).node_grad, self.cfg),
+                "adj": self.adj, "byz": self.byz, "b": self.b, "attack": self.traffic["attack"],
+                "lam": self.traffic["lam"], "t0": self.traffic["t0"], "honest": honest,
                 "nodes_per_call": self.traffic["reference_nodes_per_call"],
                 "params0": self.reference_params}
 

@@ -1,15 +1,12 @@
-"""The flat trainer (`repro.core.BridgeTrainer`) on the paper's linear task:
+"""The flat trainer (`repro.core.BridgeTrainer`) on the paper's task:
 every node's iterate one row of an [M, d] matrix, neighbour views gathered
-through the sparse [M, K] table."""
+through the sparse [M, K] table; MNIST-like batches made from the seed."""
 from __future__ import annotations
-
-import functools
 
 import jax
 
-from bench import generators
+from bench import generators, models
 from bench.paths.chunked import ChunkedCell
-from bench.reference import linear as ref_linear
 
 
 class Cell(ChunkedCell):
@@ -24,18 +21,12 @@ class Cell(ChunkedCell):
 
     def trainer(self):
         from repro.core import BridgeTrainer
-        from repro.models import small
 
-        l2 = self.cfg["l2"]
-        grad_fn = jax.value_and_grad(lambda p, b: small.linear_loss(p, b, l2=l2))
+        grad_fn = models.of(self.cfg).program_grad_fn(self.cfg)
         return lambda config: BridgeTrainer(config, grad_fn)
 
     def program_layout(self):
         from repro.core import replicate
-        from repro.models import small
 
-        return jax.eval_shape(lambda k: replicate(small.init_linear(
-            k, self.cfg["input_dim"], self.cfg["classes"]), self.m), jax.random.PRNGKey(0))
-
-    def reference_grad(self):
-        return functools.partial(ref_linear.node_grad, self.cfg)
+        init = models.of(self.cfg).program_init(self.cfg)
+        return jax.eval_shape(lambda k: replicate(init(k), self.m), jax.random.PRNGKey(0))

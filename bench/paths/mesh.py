@@ -11,10 +11,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import generators, weights
+from bench import generators, models, weights
 from bench.paths.chunked import window_program
-from bench.paths.stream import lm_batches, model_config
-from bench.reference import dense_lm as ref_lm
+from bench.paths.stream import lm_batches
 
 
 class Cell:
@@ -46,7 +45,7 @@ class Cell:
         mesh = jax.make_mesh((self.m, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2,
                              devices=devs)
         nax = ("data",)
-        mc = model_config(self.cfg, self.dtype)
+        mc = models.of(self.cfg).program_config(self.cfg, self.dtype)
         layout = jax.eval_shape(lambda k: replicate(api.build(mc).init_params(k, mc), self.m),
                                 jax.random.PRNGKey(0))
         pspecs = sharding.param_specs(mc, layout, node_axes=nax)
@@ -86,8 +85,8 @@ class Cell:
 
     def reference_spec(self) -> dict:
         return {"batches": self.batches[:self.check_calls],
-                "node_grad": functools.partial(ref_lm.node_grad, self.cfg), "adj": self.adj,
-                "byz": self.byz, "b": self.b, "attack": self.traffic["attack"],
+                "node_grad": functools.partial(models.of(self.cfg).node_grad, self.cfg),
+                "adj": self.adj, "byz": self.byz, "b": self.b, "attack": self.traffic["attack"],
                 "lam": self.traffic["lam"], "t0": self.traffic["t0"],
                 "honest": np.ones(self.m, bool),
                 "nodes_per_call": self.traffic["reference_nodes_per_call"],

@@ -47,11 +47,12 @@ def unchanged(cls):
 
 def half_batch(cls):
     """The path's driver with every node's gradient and loss taken over the
-    first half of its batch."""
+    first half of its batch, or of its sequence where the batch is one row:
+    `bench.calibrate.half_rows`, which plants the fault in the reference."""
     import jax
 
     def halve(batch):
-        return jax.tree_util.tree_map(lambda a: a[: a.shape[0] // 2], batch)
+        return jax.tree_util.tree_map(lambda a: calibrate.half_rows(a, 0), batch)
 
     class Half(cls):
         def trainer(self):  # the chunked paths: wrap the trainer's grad_fn
@@ -109,7 +110,9 @@ def _cls(path):
 
 
 @pytest.mark.parametrize("path,fault", [("paper", "unchanged"), ("paper", "half_batch"),
-                                        ("stream", "unchanged"), ("stream", "half_batch")])
+                                        ("stream", "unchanged"), ("stream", "half_batch"),
+                                        ("stream-b1", "unchanged"),
+                                        ("stream-b1", "half_batch")])
 def test_fault_is_not_correct(path, fault):
     out = tiny.run_tiny(path, cell_cls=FAULTS[fault](_cls(path)))
     assert not out["correct"], out["compared"]
@@ -130,7 +133,7 @@ def test_mesh_fault_is_not_correct(fault):
     assert not correct, compared
 
 
-@pytest.mark.parametrize("path", ["paper", "stream"])
+@pytest.mark.parametrize("path", ["paper", "stream", "stream-b1"])
 def test_control_reads_above_the_limits(path):
     """One step lower in precision fails one of the cell's numbers."""
     work, cfg, tr, limits = tiny.files(path)

@@ -12,7 +12,7 @@ from bench.tests import tiny
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
-@pytest.mark.parametrize("path", ["paper", "stream"])
+@pytest.mark.parametrize("path", ["paper", "stream", "stream-b1"])
 def test_program_matches_reference(path):
     out = tiny.run_tiny(path)
     assert out["correct"], out["compared"]

@@ -6,7 +6,7 @@ import jax
 import pytest
 
 from bench import common, weights, work
-from bench.paths import stream
+from bench.models import dense_lm
 
 
 @pytest.fixture(scope="module")
@@ -18,7 +18,7 @@ def test_weight_plan_matches_program_param_count(qwen):
     from repro.models import api
 
     _, cfg, _, _ = qwen
-    assert weights.count(cfg) == api.param_count(stream.model_config(cfg, "float32"))
+    assert weights.count(cfg) == api.param_count(dense_lm.program_config(cfg, "float32"))
     assert weights.count(cfg) == 198_172_416
 
 
@@ -27,7 +27,7 @@ def test_weight_plan_matches_program_layout(qwen):
     from repro.models import api
 
     _, cfg, tr, _ = qwen
-    mc = stream.model_config(cfg, "float32")
+    mc = dense_lm.program_config(cfg, "float32")
     layout = jax.eval_shape(lambda k: replicate(api.build(mc).init_params(k, mc), tr["nodes"]),
                             jax.random.PRNGKey(0))
     ours = jax.eval_shape(weights.make_init(cfg, tr["nodes"], 0.0, "float32"), jax.random.PRNGKey(0))
@@ -38,7 +38,7 @@ def test_matmul_params_are_all_but_embedding_and_norms(qwen):
     _, cfg, _, _ = qwen
     d, hd = cfg["hidden_size"], cfg["head_dim"]
     norms = cfg["num_hidden_layers"] * (2 * d + 2 * hd) + d
-    assert work.matmul_params(cfg) == weights.count(cfg) - cfg["vocab_size"] * d - norms
+    assert dense_lm.matmul_params(cfg) == weights.count(cfg) - cfg["vocab_size"] * d - norms
 
 
 def test_flops_per_token_by_hand(qwen):

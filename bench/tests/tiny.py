@@ -13,10 +13,11 @@ TINY_LM = {
     "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 256, "rms_norm_eps": 1e-06,
     "rope_theta": 1000000, "dtype": "float32",
 }
-# path -> (configuration, traffic, limits)
+# path (or a variant of its cell) -> (configuration, traffic, limits)
 CELLS = {
     "paper": ("mnist-linear", "m128k16", "paper-linear.m128k16"),
     "stream": ("qwen3-4b-l1v8", "stream-t2048", "qwen3-4b-l1v8.stream-t2048"),
+    "stream-b1": ("qwen3-4b-l1v8", "stream-t2048", "qwen3-4b-l1v8.stream-t2048"),
     "mesh": ("qwen3-4b-l1v8", "mesh4-t2048", "qwen3-4b-l1v8.mesh4-t2048"),
 }
 
@@ -36,8 +37,10 @@ def files(path: str):
     else:
         cfg = dict(TINY_LM)
         tr.update(seq=32, trace_calls=1)
-        if path == "stream":
+        if tr["path"] == "stream":
             tr.update(ticks_per_call=2)
+        if path == "stream-b1":  # one row a node: the faults halve its sequence
+            tr.update(batch=1)
     return work, cfg, tr, limits
 
 

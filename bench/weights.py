@@ -6,18 +6,18 @@ program made.  `init` builds every node's replica of every leaf in one jitted
 call, in the storage dtype the run asks for; each leaf draws from its own
 folded key, so the values do not depend on how many leaves there are.
 
-The pytree has the program's own layout (nested dicts, the dense family's
-layers stacked as ``[groups, pattern, ...]``); `check_layout` compares it with
+The pytree has the program's own layout, as the model family's ``plan``
+gives it (`bench.models`); `check_layout` compares it with
 the program's ``init_params`` by shapes alone, so a layout change in the
 program fails loudly at set-up instead of training different weights.
 """
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import models
 
 
 def key_from_seed(seed: int) -> jax.Array:
@@ -26,49 +26,10 @@ def key_from_seed(seed: int) -> jax.Array:
     return jax.random.fold_in(jax.random.PRNGKey(s & 0xFFFFFFFF), s >> 32)
 
 
-def linear_plan(cfg: dict) -> dict:
-    """(shape, scale) per leaf of the one-vs-all linear classifier."""
-    d, c = cfg["input_dim"], cfg["classes"]
-    return {"b": ((c,), 0.0), "w": ((d, c), cfg["init_scale"])}
-
-
-def dense_lm_plan(cfg: dict) -> dict:
-    """(shape, scale) per leaf of a dense decoder (one pattern group per
-    layer): matrices N(0, 1/fan_in), the embedding N(0, 0.02^2), RMSNorm
-    weights stored as (weight - 1) and so zero."""
-    d, f, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
-    h, kv, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
-    lead = (cfg["num_hidden_layers"], 1)
-    fan = lambda n: 1.0 / math.sqrt(n)
-    return {
-        "blocks": {
-            "attn": {
-                "k_norm": {"w": (lead + (hd,), 0.0)},
-                "q_norm": {"w": (lead + (hd,), 0.0)},
-                "wk": (lead + (d, kv * hd), fan(d)),
-                "wo": (lead + (h * hd, d), fan(h * hd)),
-                "wq": (lead + (d, h * hd), fan(d)),
-                "wv": (lead + (d, kv * hd), fan(d)),
-            },
-            "ln1": {"w": (lead + (d,), 0.0)},
-            "ln2": {"w": (lead + (d,), 0.0)},
-            "mlp": {
-                "wd": (lead + (f, d), fan(f)),
-                "wg": (lead + (d, f), fan(d)),
-                "wu": (lead + (d, f), fan(d)),
-            },
-        },
-        "embed": ((v, d), 0.02),
-        "head": ((d, v), fan(d)),
-        "ln_f": {"w": ((d,), 0.0)},
-    }
-
-
-PLANS = {"linear": linear_plan, "dense_lm": dense_lm_plan}
-
-
 def plan(cfg: dict) -> dict:
-    return PLANS[cfg["model"]](cfg)
+    """(shape, scale) per leaf: the plan of the configuration's model
+    family (`bench.models`)."""
+    return models.of(cfg).plan(cfg)
 
 
 def _is_spec(x) -> bool:
